@@ -83,6 +83,8 @@ def main(argv=None) -> int:
                          "overwrite it) and exit nonzero on >20%% sparse "
                          "per-step slowdown (benchmarks.check_regression)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(ALL)
     if args.replay and "replay" not in names:
         names.append("replay")

@@ -40,18 +40,6 @@ AXIS = "tasks"
 NODE_AXIS = "nodes"
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: `jax.shard_map(check_vma=)` on new
-    releases, `jax.experimental.shard_map.shard_map(check_rep=)` on
-    0.4.x (the replication/VMA check was renamed along the move)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def task_mesh(n_devices: Optional[int] = None) -> Mesh:
     devs = np.asarray(jax.devices()[: n_devices or len(jax.devices())])
     return Mesh(devs, (AXIS,))
@@ -155,11 +143,11 @@ def make_distributed_step(mesh: Mesh, variant: str = "sgp",
             engine_impl=engine_impl, nbrs=nbrs, buckets=buckets)
         return new_phi, aux["cost"]
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(_TASK_SHARDED_NET, _phi_spec(method), _CONSTS_SPEC, P(),
                   nbrs_spec, _buckets_spec(buckets)),
-        out_specs=(_phi_spec(method), P()))
+        out_specs=(_phi_spec(method), P()), check_vma=False)
     jitted = jax.jit(sharded)
     # keep the public step signature (net, phi, consts, sigma)
     return partial(_call_with_nbrs, jitted, nbrs, buckets)
@@ -210,12 +198,13 @@ def make_distributed_step_flows(mesh: Mesh, variant: str = "sgp",
                 engine_impl=engine_impl, nbrs=nbrs, buckets=buckets,
                 fault_plan=fault_plan, fault_state=fs)
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             step_f, mesh=mesh,
             in_specs=(_TASK_SHARDED_NET, _phi_spec(method), _CARRY_SPEC,
                       _CONSTS_SPEC, P(), nbrs_spec, _buckets_spec(buckets),
                       fs_spec),
-            out_specs=(_phi_spec(method), _CARRY_SPEC, P(), fs_spec))
+            out_specs=(_phi_spec(method), _CARRY_SPEC, P(), fs_spec),
+            check_vma=False)
         jitted = jax.jit(sharded)
         return partial(_call_with_nbrs_flows_faulted, jitted, nbrs,
                        buckets)
@@ -226,11 +215,12 @@ def make_distributed_step_flows(mesh: Mesh, variant: str = "sgp",
             sigma=sigma, kappa=kappa, method=method, psum_axis=AXIS,
             engine_impl=engine_impl, nbrs=nbrs, buckets=buckets)
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(_TASK_SHARDED_NET, _phi_spec(method), _CARRY_SPEC,
                   _CONSTS_SPEC, P(), nbrs_spec, _buckets_spec(buckets)),
-        out_specs=(_phi_spec(method), _CARRY_SPEC, P()))
+        out_specs=(_phi_spec(method), _CARRY_SPEC, P()),
+        check_vma=False)
     jitted = jax.jit(sharded)
     return partial(_call_with_nbrs_flows, jitted, nbrs, buckets)
 
@@ -832,11 +822,12 @@ def node_flows_carry_and_cost(net: CECNetwork, phi_sp: PhiSparse,
         return FlowsCarry(t_data, t_result, F, G), cost
 
     AN, N = P(AXIS, NODE_AXIS), P(NODE_AXIS)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(AN, AN, AN, AN, P(AXIS), AN, N, N,
                   N, N, N, N, N, N),
-        out_specs=(FlowsCarry(t_data=AN, t_result=AN, F=N, G=N), P()))
+        out_specs=(FlowsCarry(t_data=AN, t_result=AN, F=N, G=N), P()),
+        check_vma=False)
     carry, cost = jax.jit(sharded)(
         phi_d_sp, phi_loc, phi_r_sp, r, net.a, w, link_sp, comp_params,
         part.bnd, part.in_remap, part.in_slot, part.in_mask,

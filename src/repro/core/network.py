@@ -69,12 +69,13 @@ with three interchangeable engines (`method=`):
 The sparse rounds themselves dispatch through
 `kernels.ops.edge_rounds(..., impl=engine_impl)`:
 
-  engine_impl=None         backend default — fused Pallas kernel on TPU
-                           (index tiles resident in VMEM, the whole
-                           early-exit while-loop in ONE launch), jnp
-                           reference elsewhere
+  engine_impl=None         backend default — the jnp reference on
+                           every backend (`kernels.ops._TPU_DEFAULT`)
   engine_impl="ref"        force the jnp one-gather-per-round path
-  engine_impl="pallas"     force the Pallas TPU kernel
+  engine_impl="pallas"     force the fused Pallas kernel (index tiles
+                           resident in VMEM, the whole early-exit
+                           while-loop in ONE launch; the TPU compiler
+                           refuses its in-kernel gather)
   engine_impl="pallas_interpret"  kernel body through the Pallas
                            interpreter (CPU validation mode)
 

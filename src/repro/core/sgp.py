@@ -597,8 +597,8 @@ def _sgp_step_impl(net: CECNetwork, phi, consts: SGPConsts,
              in-module jnp path; default = kernels.ops dispatch).
     engine_impl : sparse message-passing backend for every fixed-point
              recursion (traffic, marginals, taint, path bounds), see
-             kernels.ops.edge_rounds — None = backend default (fused
-             Pallas kernel on TPU, jnp reference elsewhere).
+             kernels.ops.edge_rounds — None = backend default
+             (`kernels.ops.default_impl`).
     nbrs   : precomputed `Neighbors`; required when method="sparse"
              (the whole iteration then runs in [S, V, Dmax] edge-slot
              layout).
@@ -1518,8 +1518,8 @@ def run(net: CECNetwork, phi0, n_iters: int = 200,
     the input layout: a dense `Phi` in, a dense `Phi` back (one
     conversion after the loop); a `PhiSparse` in, a `PhiSparse` back.
     engine_impl picks the message-passing backend
-    (kernels.ops.edge_rounds; None = fused Pallas kernel on TPU, jnp
-    reference elsewhere).
+    (kernels.ops.edge_rounds; None = the backend default,
+    `kernels.ops.default_impl`).
 
     callback, if given, is invoked as ``callback(it, phi, aux, accepted)``
     where `phi` is the iterate AFTER the accept/reject decision (the new

@@ -1,9 +1,11 @@
 """Public jit'd wrappers with backend dispatch.
 
-Every op picks the Pallas TPU kernel on TPU backends and the pure-jnp
-reference otherwise (CPU CI, the 512-host-device dry-run).  Pass
-`impl="pallas_interpret"` to force the kernel body through the Pallas
-interpreter (the CPU validation mode used by the kernel tests).
+Off TPU every op runs its pure-jnp reference (CPU CI, the 512-host-device
+dry-run).  On TPU an op runs its Pallas kernel unless `_TPU_DEFAULT`
+names another implementation for it.  Pass `impl="pallas_interpret"` to
+force the kernel body through the Pallas interpreter (the CPU
+validation mode used by the kernel tests); `impl="pallas"` always means
+the compiled kernel, and on TPU it compiles or raises.
 """
 from __future__ import annotations
 
@@ -23,20 +25,30 @@ from .simplex_project import simplex_project as _proj_pallas
 from .ssd_scan import ssd_scan as _ssd_pallas
 
 
-def _backend() -> str:
-    return jax.default_backend()
+# TPU defaults that are not the Pallas kernel.  The edge_rounds kernels
+# gather with `jnp.take` over a VMEM-resident state, which the TPU
+# compiler refuses, and their weight block does not fit VMEM on
+# power-law graphs (ba_10000: 8 tasks x 10^4 x 277 f32 = 89 MB), so the
+# sparse engine's fixed points run on the XLA reference there.
+_TPU_DEFAULT = {"edge_rounds": "ref", "edge_rounds_bucketed": "ref"}
 
 
-def _pick(impl: Optional[str]) -> str:
-    if impl is not None:
-        return impl
-    return "pallas" if _backend() == "tpu" else "ref"
+def default_impl(op: str, platform: Optional[str] = None) -> str:
+    """The implementation `op` runs when the caller passes impl=None, on
+    `platform` (default: the backend JAX runs on)."""
+    if (platform or jax.default_backend()) != "tpu":
+        return "ref"
+    return _TPU_DEFAULT.get(op, "pallas")
+
+
+def _pick(impl: Optional[str], op: str) -> str:
+    return impl if impl is not None else default_impl(op)
 
 
 def flash_attention(q, k, v, causal: bool = True,
                     impl: Optional[str] = None, **kw):
     """q [B,H,S,hd]; k,v [B,KV,S,hd] -> [B,H,S,hd]."""
-    mode = _pick(impl)
+    mode = _pick(impl, "flash_attention")
     if mode == "ref":
         return _ref.flash_attention_ref(q, k, v, causal=causal)
     return _flash_pallas(q, k, v, causal=causal,
@@ -46,7 +58,7 @@ def flash_attention(q, k, v, causal: bool = True,
 def decode_attention(q, k_cache, v_cache, lengths,
                      impl: Optional[str] = None, **kw):
     """q [B,KV,G,hd]; caches [B,KV,S,hd]; lengths [B]."""
-    mode = _pick(impl)
+    mode = _pick(impl, "decode_attention")
     if mode == "ref":
         B, KV, G, hd = q.shape
         out = _ref.decode_attention_ref(
@@ -60,7 +72,7 @@ def decode_attention(q, k_cache, v_cache, lengths,
 
 def ssd_scan(x, dt, A, Bm, Cm, impl: Optional[str] = None, **kw):
     """x [B,L,H,P], dt [B,L,H], A [H], Bm/Cm [B,L,N] -> [B,L,H,P]."""
-    mode = _pick(impl)
+    mode = _pick(impl, "ssd_scan")
     if mode == "ref":
         y, _ = _ref.ssd_scan_ref(x, dt, A, Bm, Cm)
         return y
@@ -70,7 +82,7 @@ def ssd_scan(x, dt, A, Bm, Cm, impl: Optional[str] = None, **kw):
 
 def moe_gmm(x, w, impl: Optional[str] = None, **kw):
     """x [E,C,D] @ w [E,D,F] -> [E,C,F]."""
-    mode = _pick(impl)
+    mode = _pick(impl, "moe_gmm")
     if mode == "ref":
         return _ref.moe_gmm_ref(x, w)
     return _gmm_pallas(x, w, interpret=(mode == "pallas_interpret"), **kw)
@@ -86,7 +98,8 @@ def edge_rounds(w_sp, inject, nbr, mask, reduce: str = "sum",
     The Pallas path fuses gather + multiply + masked-reduce per round
     and runs the whole early-exit while-loop in one launch with the
     index tiles resident in VMEM; the jnp reference dispatches one
-    gather per round (the sparse engine's PR-1 hot path).  Edge-slot φ
+    gather per round and is the default on every backend (see
+    `_TPU_DEFAULT`).  Edge-slot φ
     (core.network.PhiSparse) feeds this directly — both backends mask
     padded weight slots internally, so slot garbage never propagates.
     """
@@ -95,7 +108,7 @@ def edge_rounds(w_sp, inject, nbr, mask, reduce: str = "sum",
             f"edge weights {w_sp.shape} are not aligned to the neighbor "
             f"tiles nbr{nbr.shape}/mask{mask.shape}; slot arrays must "
             "share the [V, Dmax] trailing layout of their Neighbors")
-    mode = _pick(impl)
+    mode = _pick(impl, "edge_rounds")
     if mode == "ref":
         return _ref.edge_rounds_ref(w_sp, inject, nbr, mask, reduce=reduce,
                                     shift=shift, max_rounds=max_rounds,
@@ -128,7 +141,7 @@ def edge_rounds_bucketed(w_sp, inject, buckets, reduce: str = "sum",
             f"tiles (V={buckets.inv.shape[0]}); slot arrays must share "
             "the [V, Dmax] trailing layout of the Neighbors the buckets "
             "were built from")
-    mode = _pick(impl)
+    mode = _pick(impl, "edge_rounds_bucketed")
     if mode == "ref":
         return _ref.edge_rounds_bucketed_ref(
             w_sp, inject, buckets, reduce=reduce, shift=shift,
@@ -176,7 +189,7 @@ def simplex_project(phi, delta, M, permitted, impl: Optional[str] = None,
     (padded coordinates are blocked, so the kernel returns 0 for them
     and the pad is sliced off); the jnp reference takes K as-is.
     """
-    mode = _pick(impl)
+    mode = _pick(impl, "simplex_project")
     if mode == "ref":
         return _ref.simplex_project_ref(phi, delta, M, permitted)
     K = phi.shape[-1]

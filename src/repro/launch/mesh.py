@@ -21,32 +21,21 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
-
-
-# jax.sharding.AxisType landed after jax 0.4.37; older releases implicitly
-# treat every axis as Auto, which is exactly what we request anyway.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def compat_make_mesh(shape, axes) -> Mesh:
-    """`jax.make_mesh` with explicit Auto axis types where supported."""
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")) -> Mesh:
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _axis_size(mesh: Mesh, name: str) -> int:

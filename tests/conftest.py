@@ -27,6 +27,7 @@ def _interpret_kernels_off_tpu(monkeypatch):
     real_pick = ops._pick
     monkeypatch.setattr(
         ops, "_pick",
-        lambda impl: ("pallas_interpret" if real_pick(impl) == "pallas"
-                      else real_pick(impl)))
+        lambda impl, op: ("pallas_interpret"
+                          if real_pick(impl, op) == "pallas"
+                          else real_pick(impl, op)))
     yield

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro import core
 from repro.core.network import PhiSparse
@@ -259,9 +260,9 @@ def _collect_shapes(jaxpr, acc):
 
 
 def _sub_jaxprs(p):
-    if isinstance(p, jax.core.ClosedJaxpr):
+    if isinstance(p, ClosedJaxpr):
         yield p.jaxpr
-    elif isinstance(p, jax.core.Jaxpr):
+    elif isinstance(p, Jaxpr):
         yield p
     elif isinstance(p, (list, tuple)):
         for q in p:

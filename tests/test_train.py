@@ -159,9 +159,8 @@ def test_data_pipeline_deterministic_and_packed():
 def test_elastic_reshard(tiny):
     """Restore-and-reshard onto a different (1-device) mesh."""
     cfg, model, params = tiny
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("model",))
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     pspecs = jax.tree.map(lambda _: P(), params)
     placed = ckpt.reshard(params, mesh, pspecs)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(placed)):
