@@ -122,6 +122,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .costs import Cost
 from ..kernels import ops as kernel_ops
 
@@ -216,6 +217,13 @@ _NBR_CACHE: OrderedDict = OrderedDict()
 _NBR_CACHE_MAX = 32
 
 
+def _to_host(x) -> np.ndarray:
+    """`np.asarray(x)`, counted as a host sync when `x` is on the device."""
+    if isinstance(x, jax.Array):
+        obs.count("host_syncs")
+    return np.asarray(x)
+
+
 def _adj_key(A: np.ndarray):
     return (A.shape[0], A.tobytes())
 
@@ -244,7 +252,12 @@ def build_neighbors(adj) -> Neighbors:
         raise ValueError(
             "build_neighbors needs a concrete adjacency; precompute it "
             "outside jit and pass it through the `nbrs=` argument")
-    A = np.asarray(adj, dtype=bool)
+    with obs.span("seed.neighbors"):
+        return _build_neighbors_impl(adj)
+
+
+def _build_neighbors_impl(adj) -> Neighbors:
+    A = np.asarray(_to_host(adj), dtype=bool)
     key = _adj_key(A)
     cached = _lru_get(_NBR_CACHE, key)
     if cached is not None:
@@ -890,11 +903,17 @@ def _spt_next_hops(net: CECNetwork,
     argmin_j w_ij + dist(j, d); the positive weight floor makes dist
     strictly decrease along chosen edges, so the tree is a DAG).
     """
-    adj = np.asarray(net.adj)
+    with obs.span("seed.next_hops"):
+        return _spt_next_hops_impl(net, weight)
+
+
+def _spt_next_hops_impl(net: CECNetwork,
+                        weight: np.ndarray | None) -> np.ndarray:
+    adj = _to_host(net.adj)
     V, S = net.V, net.S
     if weight is None:
-        weight = np.asarray(net.link_cost.d1(jnp.zeros((V, V))))
-    dests = np.asarray(net.dest)
+        weight = _to_host(net.link_cost.d1(jnp.zeros((V, V))))
+    dests = _to_host(net.dest)
     nx_all = np.full((S, V), -1, np.int64)
     idx = np.arange(V)
 
@@ -958,8 +977,8 @@ def spt_result_slots(net: CECNetwork, nbrs: Neighbors,
     S=32, V=10⁴).
     """
     nx_all = _spt_next_hops(net, weight)                        # [S, V]
-    out_nbr = np.asarray(nbrs.out_nbr)
-    out_mask = np.asarray(nbrs.out_mask)
+    out_nbr = _to_host(nbrs.out_nbr)
+    out_mask = _to_host(nbrs.out_mask)
     hit = (out_nbr[None] == nx_all[:, :, None]) \
         & out_mask[None] & (nx_all[:, :, None] >= 0)            # [S, V, D]
     return jnp.asarray(hit.astype(np.float64))

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .costs import Cost
 from .faults import fault_step_begin, fault_step_end, init_fault_state
 from .marginals import BIG, Marginals, compute_marginals
@@ -852,34 +853,40 @@ def init_run_state(net: CECNetwork, phi0, min_scale: float = 0.05,
     mask through every step: inactive slots' φ rows are frozen bitwise
     and (their r/a rows being zero) contribute exactly zero traffic and
     cost.  None is the fixed-S engine, bit for bit."""
-    if method == "sparse":
-        nbrs = build_neighbors(net.adj) if nbrs is None else nbrs
-        if bucketed and buckets is None:
-            buckets = build_buckets(net.adj)
-    else:
-        nbrs = None
-        buckets = None
-    if method == "sparse" and not isinstance(phi0, PhiSparse):
-        phi0 = phi_to_sparse(phi0, nbrs)   # boundary: iterate in slots
-    fl0, T0 = flows_carry_and_cost_jit(net, phi0, method, nbrs=nbrs,
-                                       engine_impl=engine_impl,
-                                       buckets=buckets)
-    consts = make_consts(net, T0, min_scale)
-    fault_state = None
-    if fault_plan is not None:
-        fault_state = init_fault_state(
-            net, phi0, fl0, fault_plan, rng=fault_rng, method=method,
-            nbrs=nbrs, engine_impl=engine_impl, buckets=buckets)
-    guard_state = None
-    if guards is not None:
-        from .guards import init_guard_state   # lazy: guards imports sgp
-        guard_state = init_guard_state(phi0, fl0, T0, guards)
-    return RunState(phi=phi0, consts=consts, nbrs=nbrs, method=method,
-                    costs=[float(T0)], min_scale=min_scale, rng=rng,
-                    flows=fl0, buckets=buckets,
-                    fault_plan=fault_plan, fault_state=fault_state,
-                    guard_cfg=guards, guard_state=guard_state,
-                    active=active)
+    with obs.span("sgp.init"):
+        if method == "sparse":
+            nbrs = build_neighbors(net.adj) if nbrs is None else nbrs
+            if bucketed and buckets is None:
+                buckets = build_buckets(net.adj)
+        else:
+            nbrs = None
+            buckets = None
+        if method == "sparse" and not isinstance(phi0, PhiSparse):
+            phi0 = phi_to_sparse(phi0, nbrs)   # boundary: iterate in slots
+        with obs.span("sgp.init.flows"):
+            fl0, T0 = flows_carry_and_cost_jit(net, phi0, method, nbrs=nbrs,
+                                               engine_impl=engine_impl,
+                                               buckets=buckets)
+        with obs.span("sgp.init.sync"):
+            obs.count("host_syncs")
+            cost0 = float(T0)
+        with obs.span("sgp.init.consts"):
+            consts = make_consts(net, T0, min_scale)
+        fault_state = None
+        if fault_plan is not None:
+            fault_state = init_fault_state(
+                net, phi0, fl0, fault_plan, rng=fault_rng, method=method,
+                nbrs=nbrs, engine_impl=engine_impl, buckets=buckets)
+        guard_state = None
+        if guards is not None:
+            from .guards import init_guard_state   # lazy: guards imports sgp
+            guard_state = init_guard_state(phi0, fl0, T0, guards)
+        return RunState(phi=phi0, consts=consts, nbrs=nbrs, method=method,
+                        costs=[cost0], min_scale=min_scale, rng=rng,
+                        flows=fl0, buckets=buckets,
+                        fault_plan=fault_plan, fault_state=fault_state,
+                        guard_cfg=guards, guard_state=guard_state,
+                        active=active)
 
 
 def _accept_update_impl(phi_new, fl_new, cost_new, phi, fl, sigma, prev,
@@ -1040,6 +1047,7 @@ def run_chunk(net: CECNetwork, state: RunState, n_iters: int,
             engine_impl=engine_impl, nbrs=nbrs, buckets=state.buckets,
             with_aux=callback is not None, active=state.active)
         phi_new, fl_new, cost_new = out[:3]
+        obs.count("host_syncs")
         new_cost = float(cost_new)   # the host driver's per-iteration sync
         accepted, sigma, stop = accept_step(new_cost, costs[-1], sigma,
                                             scaling, variant)
@@ -1062,6 +1070,7 @@ def run_chunk(net: CECNetwork, state: RunState, n_iters: int,
         if accepted and _tol_converged(costs, tol):
             state.stopped = True
             break
+    obs.count("sgp.iterations", done - state.it)
     state.phi, state.consts, state.flows = phi, consts, fl
     state.sigma, state.n_rejected, state.rng = sigma, n_rejected, rng
     state.it = done
@@ -1080,6 +1089,7 @@ def _fold_fused_histories(state, sigma, n_rej, stopped, cost_hist,
     histories); the fetched host histories come back as
     (cost_hist, take_hist, live_hist, extra) so callers can render
     per-iteration records without a second sync."""
+    obs.count("host_syncs")
     sigma, n_rej, stopped, cost_hist, take_hist, live_hist, extra = \
         jax.device_get((sigma, n_rej, stopped, cost_hist, take_hist,
                         live_hist, extra))
@@ -1187,70 +1197,76 @@ class FusedStream:
         flags accumulate as device scalars for `finish`."""
         assert not self._finished, "stream already finished"
         net, state, o = self.net, self.state, self._o
-        for it in range(self._it, self._it + n_iters):
-            if self._refresh and it > 0 and it % o["refresh_every"] == 0:
-                fresh = _make_consts_jit(net, self._prev, state.min_scale)
-                stopped = self._stopped
-                self._consts = jax.tree.map(
-                    lambda old, new: jnp.where(stopped, old, new),
-                    self._consts, fresh)
-            mask_d = mask_r = rng_new = None
-            if self._use_rng:
-                rng_new, k1, k2 = jax.random.split(self._rng, 3)
-                mask_d = jax.random.bernoulli(k1, 1.0 - o["async_frac"],
-                                              (net.S, net.V))
-                mask_r = jax.random.bernoulli(k2, 1.0 - o["async_frac"],
-                                              (net.S, net.V))
-            out = sgp_step_flows(
-                net, self._phi, self._fl, self._consts,
-                variant=o["variant"], beta=o["beta"],
-                mask_data=mask_d, mask_result=mask_r,
-                allowed_data=o["allowed_data"],
-                allowed_result=o["allowed_result"],
-                method=state.method, use_blocking=o["use_blocking"],
-                scaling=o["scaling"], sigma=self._sigma, kappa=o["kappa"],
-                proj_impl=o["proj_impl"], engine_impl=o["engine_impl"],
-                nbrs=state.nbrs, buckets=state.buckets,
-                fault_plan=state.fault_plan, fault_state=self._fs,
-                active=self._active)
-            stopped_pre = self._stopped
-            if self._faulted:
-                phi_new, fl_new, cost_new, fs_new = out
-                # a stopped carry freezes the fault state too, so chunked
-                # resumption past a stop stays bitwise (the dead
-                # dispatches must not advance the fault rng/ring)
-                self._fs = jax.tree.map(
-                    lambda new, old: jnp.where(stopped_pre, old, new),
-                    fs_new, self._fs)
-            else:
-                phi_new, fl_new, cost_new = out
-            if self._guarded:
-                cfg = state.guard_cfg
-                do_ckpt = bool(cfg.checkpoint_every
-                               and it % cfg.checkpoint_every == 0)
-                (self._phi, self._fl, self._sigma, self._prev,
-                 self._n_costs, self._n_rej, self._stopped, self._rng,
-                 take, live, self._gs, code, rolled, ck_cost) = \
-                    self._guarded_update(
-                        phi_new, fl_new, cost_new, self._phi, self._fl,
-                        self._sigma, self._prev, self._n_costs,
-                        self._n_rej, self._stopped, rng_new, self._rng,
-                        self._tol32, self._gs, state.nbrs,
-                        adaptive=self._adaptive, cfg=cfg, do_ckpt=do_ckpt)
-                self._code_h.append(code)
-                self._roll_h.append(rolled)
-                self._ck_h.append(ck_cost)
-            else:
-                (self._phi, self._fl, self._sigma, self._prev,
-                 self._n_costs, self._n_rej, self._stopped, self._rng,
-                 take, live) = _accept_update(
-                    phi_new, fl_new, cost_new, self._phi, self._fl,
-                    self._sigma, self._prev, self._n_costs, self._n_rej,
-                    self._stopped, rng_new, self._rng, self._tol32,
-                    adaptive=self._adaptive)
-            self._cost_h.append(cost_new)
-            self._take_h.append(take)
-            self._live_h.append(live)
+        obs.count("sgp.iterations", n_iters)
+        with obs.span("sgp.advance"):
+            for it in range(self._it, self._it + n_iters):
+                if self._refresh and it > 0 and it % o["refresh_every"] == 0:
+                    fresh = _make_consts_jit(net, self._prev, state.min_scale)
+                    stopped = self._stopped
+                    self._consts = jax.tree.map(
+                        lambda old, new: jnp.where(stopped, old, new),
+                        self._consts, fresh)
+                mask_d = mask_r = rng_new = None
+                if self._use_rng:
+                    rng_new, k1, k2 = jax.random.split(self._rng, 3)
+                    mask_d = jax.random.bernoulli(k1, 1.0 - o["async_frac"],
+                                                  (net.S, net.V))
+                    mask_r = jax.random.bernoulli(k2, 1.0 - o["async_frac"],
+                                                  (net.S, net.V))
+                with obs.span("sgp.step"):
+                    out = sgp_step_flows(
+                        net, self._phi, self._fl, self._consts,
+                        variant=o["variant"], beta=o["beta"],
+                        mask_data=mask_d, mask_result=mask_r,
+                        allowed_data=o["allowed_data"],
+                        allowed_result=o["allowed_result"],
+                        method=state.method, use_blocking=o["use_blocking"],
+                        scaling=o["scaling"], sigma=self._sigma,
+                        kappa=o["kappa"],
+                        proj_impl=o["proj_impl"], engine_impl=o["engine_impl"],
+                        nbrs=state.nbrs, buckets=state.buckets,
+                        fault_plan=state.fault_plan, fault_state=self._fs,
+                        active=self._active)
+                stopped_pre = self._stopped
+                if self._faulted:
+                    phi_new, fl_new, cost_new, fs_new = out
+                    # a stopped carry freezes the fault state too, so
+                    # chunked resumption past a stop stays bitwise (the
+                    # dead dispatches must not advance the fault rng/ring)
+                    self._fs = jax.tree.map(
+                        lambda new, old: jnp.where(stopped_pre, old, new),
+                        fs_new, self._fs)
+                else:
+                    phi_new, fl_new, cost_new = out
+                with obs.span("sgp.accept"):
+                    if self._guarded:
+                        cfg = state.guard_cfg
+                        do_ckpt = bool(cfg.checkpoint_every
+                                       and it % cfg.checkpoint_every == 0)
+                        (self._phi, self._fl, self._sigma, self._prev,
+                         self._n_costs, self._n_rej, self._stopped, self._rng,
+                         take, live, self._gs, code, rolled, ck_cost) = \
+                            self._guarded_update(
+                                phi_new, fl_new, cost_new, self._phi, self._fl,
+                                self._sigma, self._prev, self._n_costs,
+                                self._n_rej, self._stopped, rng_new, self._rng,
+                                self._tol32, self._gs, state.nbrs,
+                                adaptive=self._adaptive, cfg=cfg,
+                                do_ckpt=do_ckpt)
+                        self._code_h.append(code)
+                        self._roll_h.append(rolled)
+                        self._ck_h.append(ck_cost)
+                    else:
+                        (self._phi, self._fl, self._sigma, self._prev,
+                         self._n_costs, self._n_rej, self._stopped, self._rng,
+                         take, live) = _accept_update(
+                            phi_new, fl_new, cost_new, self._phi, self._fl,
+                            self._sigma, self._prev, self._n_costs,
+                            self._n_rej, self._stopped, rng_new, self._rng,
+                            self._tol32, adaptive=self._adaptive)
+                self._cost_h.append(cost_new)
+                self._take_h.append(take)
+                self._live_h.append(live)
         self._it += n_iters
         return self
 
@@ -1348,6 +1364,10 @@ class FusedStream:
         `_init_state` + `run_chunk` would have left behind)."""
         assert not self._finished, "stream already finished"
         self._finished = True
+        with obs.span("sgp.finish"):
+            return self._finish()
+
+    def _finish(self) -> list:
         state = self.state
         extra = ((self._code_h, self._roll_h, self._ck_h)
                  if self._guarded else None)
@@ -1367,6 +1387,7 @@ class FusedStream:
             if self._use_rng:
                 state.rng = self._rng
             return []
+        obs.count("host_syncs")
         (sigma, n_rej, stopped, cost_h, take_h, live_h, extra_h,
          marks) = jax.device_get((
             self._sigma, self._n_rej, self._stopped, self._cost_h,
@@ -1432,14 +1453,15 @@ def _run_chunk_fused(net: CECNetwork, state: RunState, fl, n_iters: int,
     pipelined iterations are discarded no-ops, so prefer right-sizing
     chunks when stops are expected.
     """
-    stream = FusedStream(net, state, fl=fl, variant=variant, beta=beta,
-                         allowed_data=allowed_data,
-                         allowed_result=allowed_result,
-                         async_frac=async_frac, tol=tol,
-                         use_blocking=use_blocking,
-                         refresh_every=refresh_every, scaling=scaling,
-                         kappa=kappa, proj_impl=proj_impl,
-                         engine_impl=engine_impl)
+    with obs.span("sgp.open"):
+        stream = FusedStream(net, state, fl=fl, variant=variant, beta=beta,
+                             allowed_data=allowed_data,
+                             allowed_result=allowed_result,
+                             async_frac=async_frac, tol=tol,
+                             use_blocking=use_blocking,
+                             refresh_every=refresh_every, scaling=scaling,
+                             kappa=kappa, proj_impl=proj_impl,
+                             engine_impl=engine_impl)
     stream.advance(n_iters)
     stream.finish()
     return state
@@ -1554,25 +1576,29 @@ def run(net: CECNetwork, phi0, n_iters: int = 200,
 
     Returns (phi_final, history dict of per-iteration costs).
     """
-    dense_in = not isinstance(phi0, PhiSparse)
-    state = init_run_state(net, phi0, min_scale=min_scale, method=method,
-                           rng=rng, engine_impl=engine_impl,
-                           bucketed=bucketed, fault_plan=fault_plan,
-                           fault_rng=fault_rng, guards=guards)
-    state = run_chunk(net, state, n_iters, variant=variant, beta=beta,
-                      allowed_data=allowed_data,
-                      allowed_result=allowed_result,
-                      async_frac=async_frac, tol=tol, callback=callback,
-                      use_blocking=use_blocking, refresh_every=refresh_every,
-                      scaling=scaling, kappa=kappa, proj_impl=proj_impl,
-                      engine_impl=engine_impl, driver=driver)
-    phi = state.phi
-    if method == "sparse" and dense_in:
-        phi = sparse_to_phi(phi, state.nbrs, net.V)  # boundary: back to dense
-    hist = {"costs": state.costs, "final_cost": state.costs[-1],
-            "n_rejected": state.n_rejected}
-    if guards is not None:
-        hist["guard_events"] = state.guard_events
-    if state.fault_state is not None:
-        hist["n_corrupt"] = int(state.fault_state.n_corrupt)
-    return phi, hist
+    with obs.span("sgp.run"):
+        dense_in = not isinstance(phi0, PhiSparse)
+        state = init_run_state(net, phi0, min_scale=min_scale,
+                               method=method, rng=rng,
+                               engine_impl=engine_impl,
+                               bucketed=bucketed, fault_plan=fault_plan,
+                               fault_rng=fault_rng, guards=guards)
+        state = run_chunk(net, state, n_iters, variant=variant, beta=beta,
+                          allowed_data=allowed_data,
+                          allowed_result=allowed_result,
+                          async_frac=async_frac, tol=tol, callback=callback,
+                          use_blocking=use_blocking,
+                          refresh_every=refresh_every, scaling=scaling,
+                          kappa=kappa, proj_impl=proj_impl,
+                          engine_impl=engine_impl, driver=driver)
+        phi = state.phi
+        if method == "sparse" and dense_in:
+            # boundary: back to dense
+            phi = sparse_to_phi(phi, state.nbrs, net.V)
+        hist = {"costs": state.costs, "final_cost": state.costs[-1],
+                "n_rejected": state.n_rejected}
+        if guards is not None:
+            hist["guard_events"] = state.guard_events
+        if state.fault_state is not None:
+            hist["n_corrupt"] = int(state.fault_state.n_corrupt)
+        return phi, hist

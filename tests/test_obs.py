@@ -1,0 +1,118 @@
+"""repro.obs: host spans and counters, off by default, and the driver's
+and seeding's spans and counters on a real solve."""
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from repro import core, obs
+
+
+@pytest.fixture(autouse=True)
+def _obs_off():
+    obs.disable()
+    obs.reset()
+    yield
+    obs.disable()
+    obs.reset()
+
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_off_records_nothing():
+    assert obs.span("a") is obs.span("b") is obs._OFF
+    with obs.span("a"):
+        obs.count("c", 3)
+    assert obs.snapshot() == {"spans": {}, "counters": {}}
+
+
+def test_nested_spans_and_counters():
+    obs.enable()
+    with obs.span("outer"):
+        _spin(0.002)
+        for _ in range(3):
+            with obs.span("inner"):
+                _spin(0.001)
+                obs.count("n")
+        obs.count("n", 4)
+    with obs.span("outer"):
+        pass
+    obs.disable()
+    with obs.span("outer"):
+        obs.count("n")
+    snap = obs.snapshot()
+    outer, inner = snap["spans"]["outer"], snap["spans"]["inner"]
+    assert snap["counters"] == {"n": 7}
+    assert outer["calls"] == 2 and inner["calls"] == 3
+    assert inner["self_s"] == inner["total_s"] >= 0.003
+    assert outer["total_s"] >= inner["total_s"] + 0.002
+    assert outer["self_s"] == pytest.approx(
+        outer["total_s"] - inner["total_s"], abs=1e-12)
+    obs.reset()
+    assert obs.snapshot() == {"spans": {}, "counters": {}}
+
+
+def _geant():
+    net = core.make_scenario(core.TABLE_II["geant"])
+    return net, core.spt_phi_sparse(net, core.build_neighbors(net.adj))
+
+
+def test_run_spans_counters_and_bitwise_result():
+    net, phi0 = _geant()
+    phi_off, hist_off = core.run(net, phi0, n_iters=5, method="sparse")
+    obs.enable()
+    phi_on, hist_on = core.run(net, phi0, n_iters=5, method="sparse")
+    obs.disable()
+    snap = obs.snapshot()
+    calls = {k: v["calls"] for k, v in snap["spans"].items()}
+    assert calls == {"sgp.run": 1, "sgp.init": 1, "seed.neighbors": 1,
+                     "sgp.init.flows": 1, "sgp.init.sync": 1,
+                     "sgp.init.consts": 1, "sgp.open": 1, "sgp.advance": 1,
+                     "sgp.step": 5, "sgp.accept": 5, "sgp.finish": 1}
+    # the adjacency pull of build_neighbors, float(T0), finish's device_get
+    assert snap["counters"] == {"sgp.iterations": 5, "host_syncs": 3}
+    spans = snap["spans"]
+    inside_run = sum(spans[k]["total_s"] for k in
+                     ("sgp.init", "sgp.open", "sgp.advance", "sgp.finish"))
+    assert spans["sgp.run"]["self_s"] == pytest.approx(
+        spans["sgp.run"]["total_s"] - inside_run, abs=1e-12)
+    assert spans["sgp.advance"]["self_s"] == pytest.approx(
+        spans["sgp.advance"]["total_s"] - spans["sgp.step"]["total_s"]
+        - spans["sgp.accept"]["total_s"], abs=1e-12)
+    assert hist_on["costs"] == hist_off["costs"]
+    for a, b in zip(jax.tree.leaves(phi_on), jax.tree.leaves(phi_off)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_cold_solve_host_syncs():
+    """Seeding and solve as one cold solve: 9 device-to-host fetches."""
+    net, _ = _geant()
+    obs.enable()
+    nbrs = core.build_neighbors(net.adj)        # adjacency
+    # next hops: adjacency, link weights, destinations; slots: out_nbr,
+    # out_mask
+    phi0 = core.spt_phi_sparse(net, nbrs)
+    seeded = obs.snapshot()
+    core.run(net, phi0, n_iters=3, method="sparse")
+    obs.disable()
+    snap = obs.snapshot()
+    assert seeded["counters"] == {"host_syncs": 6}
+    assert {k: v["calls"] for k, v in seeded["spans"].items()} == {
+        "seed.neighbors": 1, "seed.next_hops": 1}
+    assert snap["counters"]["host_syncs"] == 9
+
+
+def test_host_driver_counts_each_cost_fetch():
+    net, phi0 = _geant()
+    obs.enable()
+    core.run(net, phi0, n_iters=4, method="sparse", driver="host")
+    obs.disable()
+    snap = obs.snapshot()
+    # build_neighbors' adjacency, float(T0), one float(cost) an iteration
+    assert snap["counters"] == {"sgp.iterations": 4, "host_syncs": 6}
+    assert "sgp.advance" not in snap["spans"]
