@@ -29,9 +29,10 @@ seeded, composable, ON-DEVICE fault model:
                                  the carry (exactly the failure mode
                                  `core.guards` exists to catch)
 
-Faults compose as masks/selects inside the SAME jitted
-`sgp_step_flows` executable both drivers dispatch, so an injected run
-stays one async dispatch per iteration: the `FaultPlan` (static,
+Faults compose as masks/selects inside the step of the SAME jitted
+`sgp._sgp_block` executable both drivers dispatch (the `FaultState`
+rides the block's loop carry), so an injected run never syncs the
+host inside a chunk: the `FaultPlan` (static,
 hashable — which injectors are armed and how hard) picks the traced
 code at compile time, and the `FaultState` pytree (rng, staleness
 ring, dropout hold, corruption count) rides the driver carry.  A plan
@@ -66,7 +67,7 @@ class FaultPlan:
     participation_p=1.0) traces the fault code yet reproduces the
     fault-free trajectory up to compilation (same accept/reject
     decisions, ulp-level cost noise).  Plain frozen dataclass — hashable,
-    so `sgp_step_flows` caches one executable per distinct plan.
+    so the driver's block caches one executable per distinct plan.
     """
     participation_p: Optional[float] = None  # P(node updates) per iter
     staleness_k: int = 0                     # max marginal age (iters)

@@ -9,9 +9,10 @@ dispatch carries the whole launch overhead for one small instance, and
 each per-iteration host sync stalls the pipeline B times per round.
 
 This driver stacks the B networks leaf-wise (leading lane axis) and
-runs `jax.vmap` over the SAME step/accept kernels the solo fused
-driver uses (`sgp._sgp_step_flows_impl` + `sgp._accept_update_impl`),
-so one dispatch per iteration advances the whole fleet and ONE
+runs `jax.vmap` over the SAME step/accept pair the solo driver's
+block runs in its loop body (`sgp._sgp_step_flows_impl` +
+`sgp._accept_update_impl`, see `sgp._sgp_block`), so one dispatch per
+iteration advances the whole fleet and ONE
 `jax.device_get` at the end of `run_fleet` fetches every lane's
 accepted-cost trajectory.  Because the batched kernels are the solo
 kernels vmapped — reductions stay on their original axes, the QP

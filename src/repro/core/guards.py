@@ -4,10 +4,11 @@
 nodes, poisoned rows); this module makes it NOTICE and RECOVER, on
 device, without breaking the fused chunk's one-sync contract.
 
-Per iteration, `_guarded_update_impl` runs the exact accept/reject
-carry update (`sgp._accept_update_impl`, op-for-op — a guarded
-fault-free run is bitwise the unguarded one) and then checks the
-POST-accept carry against four sentinels:
+Per iteration, on the candidate of a one-iteration `sgp._sgp_block`
+dispatch, `_guarded_update_impl` runs the exact accept/reject carry
+update (`sgp._accept_update_impl`, op-for-op — the update the block's
+own loop runs, so a guarded fault-free run is bitwise the unguarded
+one) and then checks the POST-accept carry against four sentinels:
 
   1 nonfinite_cost    the carried best cost went NaN/Inf
   2 nonfinite_phi     any φ leaf holds a non-finite value (the landing

@@ -36,7 +36,8 @@ import numpy as np
 
 from .. import obs
 from .costs import Cost
-from .faults import fault_step_begin, fault_step_end, init_fault_state
+from .faults import (_marginals_jit, fault_step_begin, fault_step_end,
+                     init_fault_state)
 from .marginals import BIG, Marginals, compute_marginals
 from .network import (CECNetwork, Flows, FlowsCarry, Neighbors, Phi,
                       PhiSparse, _phi_edge_views, build_buckets,
@@ -657,8 +658,7 @@ def _sgp_step_flows_impl(net: CECNetwork, phi, fl, consts: SGPConsts,
                          proj_impl: Optional[str] = None,
                          engine_impl: Optional[str] = None,
                          nbrs: Optional[Neighbors] = None,
-                         buckets=None, with_aux: bool = False,
-                         fault_plan=None, fault_state=None,
+                         buckets=None, fault_plan=None, fault_state=None,
                          active: Optional[jnp.ndarray] = None):
     """One DRIVER iteration: propose the candidate from the current
     iterate's carried flows, then measure the candidate (flows + cost).
@@ -672,24 +672,22 @@ def _sgp_step_flows_impl(net: CECNetwork, phi, fl, consts: SGPConsts,
     `fault_plan=None` (the default) traces the identical program as
     before the fault layer existed.
 
-    This is the primitive both the python-loop reference and the fused
-    pipelined driver dispatch — the SAME jitted executable, which is
-    what makes their trajectories bitwise identical (XLA fusion is
-    graph-context-dependent, so re-tracing the same ops inside a larger
-    program does NOT reproduce the same floats; sharing the compiled
-    step does).  Per iteration it runs exactly one `compute_flows` — of
-    the candidate; the current iterate's flows arrive via `fl` (a
-    `FlowsCarry`, computed when IT was the candidate, or by the
-    boundary `network.flows_carry_and_cost` for φ⁰).  Returns
-    (phi_new, carry_new, cost_new[, marginals-of-`phi` if with_aux]).
+    This is the step of the `_sgp_block` loop body, and every
+    single-device driver — the python-loop reference and the fused
+    pipeline alike — dispatches it only through that block, so they
+    share ONE compiled loop body, which is what makes their trajectories
+    bitwise identical (XLA fusion is graph-context-dependent, so
+    re-tracing the same ops inside a larger program does NOT reproduce
+    the same floats; sharing the compiled block does).  Per iteration
+    it runs exactly one `compute_flows` — of the candidate; the current
+    iterate's flows arrive via `fl` (a `FlowsCarry`, computed when IT
+    was the candidate, or by the boundary
+    `network.flows_carry_and_cost` for φ⁰).  Returns
+    (phi_new, carry_new, cost_new).
     """
     faulted = fault_plan is not None and fault_state is not None
     mg_in = None
     if faulted:
-        if with_aux:
-            raise ValueError("with_aux is not supported under fault "
-                             "injection (the aux marginals would be the "
-                             "injected, not the true, ones)")
         mg_in, pmask, k_cor, fs_mid = fault_step_begin(
             net, phi, fl, fault_state, fault_plan, method, nbrs,
             engine_impl, buckets)
@@ -724,8 +722,6 @@ def _sgp_step_flows_impl(net: CECNetwork, phi, fl, consts: SGPConsts,
             net, phi_new, k_cor, fault_plan, fs_mid, nbrs=nbrs,
             psum_axis=psum_axis)
         return phi_new, carry_new, cost_new, fs_new
-    if with_aux:
-        return phi_new, carry_new, cost_new, mg
     return phi_new, carry_new, cost_new
 
 
@@ -733,7 +729,7 @@ sgp_step_flows = jax.jit(
     _sgp_step_flows_impl,
     static_argnames=("variant", "method", "use_blocking", "scaling",
                      "kappa", "psum_axis", "proj_impl", "engine_impl",
-                     "with_aux", "fault_plan"))
+                     "fault_plan"))
 
 
 # ------------------------------------------------------------------- driver
@@ -933,11 +929,115 @@ def _accept_update_impl(phi_new, fl_new, cost_new, phi, fl, sigma, prev,
 
 _accept_update = jax.jit(_accept_update_impl, static_argnames=("adaptive",))
 
+# History capacity of one block dispatch: a block runs up to BLOCK
+# driver iterations and writes one slot of each history buffer per
+# iteration.  It sizes the buffers, not the work done.
+BLOCK = 256
+
+
+def _sgp_block_impl(net: CECNetwork, phi, fl, consts: SGPConsts, sigma,
+                    prev, n_costs, n_rej, stopped, tol, n, *, step,
+                    adaptive: bool, fault_plan=None, fault_state=None,
+                    rng=None, async_frac: float = 0.0, **step_kw):
+    """Up to `n` (<= BLOCK) driver iterations as one on-device loop.
+
+    Each pass is one `step` call (`sgp_step_flows`, or whatever the
+    module holds under that name when the block is dispatched) followed
+    by the `_accept_update_impl` carry update; the loop ends early once
+    the carry stops (sigma blow-up / tol exit), so slots that never ran
+    read live=False — the frozen no-op of a stopped carry, which leaves
+    the fault state and the rng frozen too.  With a fault plan the
+    `FaultState` rides the loop carry; with an `rng` each pass draws the
+    Theorem-2 row masks (keep probability 1 - async_frac) from it.
+    Every single-device driver dispatches the step only through this
+    block: the fused path with dynamic `n`, the host loop and the
+    guarded fused path with n=1 — one compiled loop body, so their
+    trajectories are bitwise identical.
+
+    Returns (carry, histories, candidate): the carry (phi, fl, sigma,
+    prev, n_costs, n_rej, stopped, fault_state, rng), the [BLOCK]
+    candidate cost / take / live buffers, and the last candidate
+    (phi_new, fl_new, cost_new) — the carry's own (phi, fl, prev) if no
+    pass ran.
+    """
+    faulted = fault_plan is not None and fault_state is not None
+
+    def cond(c):
+        return (c[0] < n) & ~c[7]
+
+    def body(c):
+        i, phi, fl, sigma, prev, n_costs, n_rej, stopped, fs, key, hist, _ = c
+        mask_d = mask_r = key_new = None
+        if key is not None:
+            key_new, k1, k2 = jax.random.split(key, 3)
+            mask_d = jax.random.bernoulli(k1, 1.0 - async_frac,
+                                          (net.S, net.V))
+            mask_r = jax.random.bernoulli(k2, 1.0 - async_frac,
+                                          (net.S, net.V))
+        out = step(net, phi, fl, consts, sigma=sigma, mask_data=mask_d,
+                   mask_result=mask_r, fault_plan=fault_plan,
+                   fault_state=fs, **step_kw)
+        if faulted:
+            phi_new, fl_new, cost_new, fs = out
+        else:
+            phi_new, fl_new, cost_new = out
+        (phi, fl, sigma, prev, n_costs, n_rej, stopped, key, take,
+         live) = _accept_update_impl(
+            phi_new, fl_new, cost_new, phi, fl, sigma, prev, n_costs,
+            n_rej, stopped, key_new, key, tol, adaptive)
+        cost_h, take_h, live_h = hist
+        hist = (cost_h.at[i].set(cost_new), take_h.at[i].set(take),
+                live_h.at[i].set(live))
+        return (i + 1, phi, fl, sigma, prev, n_costs, n_rej, stopped, fs,
+                key, hist, (phi_new, fl_new, cost_new))
+
+    hist0 = (jnp.zeros((BLOCK,), jnp.float32),
+             jnp.zeros((BLOCK,), bool), jnp.zeros((BLOCK,), bool))
+    c = jax.lax.while_loop(cond, body, (
+        jnp.asarray(0, jnp.int32), phi, fl, sigma, prev, n_costs, n_rej,
+        stopped, fault_state, rng, hist0, (phi, fl, prev)))
+    return c[1:10], c[10], c[11]
+
+
+_sgp_block = jax.jit(
+    _sgp_block_impl,
+    static_argnames=("step", "adaptive", "fault_plan", "async_frac",
+                     "variant", "method", "use_blocking", "scaling", "kappa",
+                     "proj_impl", "engine_impl"))
+
+
+def _block_opts(state: RunState, *, variant: str, beta: float,
+                allowed_data, allowed_result, async_frac: float,
+                use_blocking: bool, scaling: str, kappa: float,
+                proj_impl: Optional[str],
+                engine_impl: Optional[str]) -> dict:
+    """The chunk-invariant keywords of a `_dispatch_block` call."""
+    return dict(variant=variant, beta=beta, allowed_data=allowed_data,
+                allowed_result=allowed_result, async_frac=async_frac,
+                method=state.method, use_blocking=use_blocking,
+                scaling=scaling, kappa=kappa, proj_impl=proj_impl,
+                engine_impl=engine_impl, nbrs=state.nbrs,
+                buckets=state.buckets, fault_plan=state.fault_plan,
+                adaptive=scaling == "adaptive" and variant == "sgp")
+
+
+def _dispatch_block(net: CECNetwork, phi, fl, consts: SGPConsts, carry,
+                    n: int, opts: dict, **kw):
+    """One `_sgp_block` dispatch of `n` iterations from the device carry
+    (sigma, prev, n_costs, n_rej, stopped, tol); `kw` holds the
+    per-dispatch device inputs (fault_state, rng, active).  The step is
+    read from the module at every call, so a step swapped in under the
+    name `sgp_step_flows` gets its own executable instead of a stale
+    one."""
+    obs.count("sgp.blocks")
+    return _sgp_block(net, phi, fl, consts, *carry, np.int32(n),
+                      step=sgp_step_flows, **opts, **kw)
+
+
 # the paper-scaling consts refresh must be the SAME executable in both
 # drivers (eager vs jitted compilation of the d2_sup chains need not
 # round identically), so both call this
 _make_consts_jit = jax.jit(make_consts)
-
 
 def _entry_flows(net: CECNetwork, state: RunState,
                  engine_impl: Optional[str]):
@@ -976,10 +1076,12 @@ def run_chunk(net: CECNetwork, state: RunState, n_iters: int,
         syncs and a single `device_get` at the end; "host" is the
         per-iteration python loop, the bitwise reference oracle
         (identical `costs`/sigma/rng trajectory: both drivers dispatch
-        the SAME compiled `sgp_step_flows` executable, and the fused
-        accept/select kernel mirrors `accept_step`'s f32 arithmetic
-        op-for-op).  None (default) picks "fused" unless a `callback`
-        needs the host loop's per-iteration hook.
+        the SAME compiled `_sgp_block` executable — the host loop one
+        iteration at a time, deciding each step with its own
+        `accept_step` — and the fused accept/select kernel mirrors
+        `accept_step`'s f32 arithmetic op-for-op).  None (default)
+        picks "fused" unless a `callback` needs the host loop's
+        per-iteration hook.
 
     The tol early-exit fires only after an ACCEPTED step (both
     drivers): a rejected iteration leaves `costs` unchanged, and
@@ -1026,6 +1128,18 @@ def run_chunk(net: CECNetwork, state: RunState, n_iters: int,
     phi, consts, nbrs = state.phi, state.consts, state.nbrs
     method, costs = state.method, state.costs
     sigma, n_rejected, rng = state.sigma, state.n_rejected, state.rng
+    opts = _block_opts(state, variant=variant, beta=beta,
+                       allowed_data=allowed_data,
+                       allowed_result=allowed_result, async_frac=async_frac,
+                       use_blocking=use_blocking, scaling=scaling,
+                       kappa=kappa, proj_impl=proj_impl,
+                       engine_impl=engine_impl)
+    use_rng = async_frac > 0.0 and rng is not None
+    # the block's own carry update is discarded (all but the rng it
+    # split for the async masks): this loop decides with `accept_step`
+    # on the host, the oracle the fused selects mirror
+    idle = (jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
+            jnp.asarray(False), jnp.float32(tol))
     done = state.it                  # iterations executed so far (global)
     for it in range(state.it, state.it + n_iters):
         done = it + 1
@@ -1033,20 +1147,12 @@ def run_chunk(net: CECNetwork, state: RunState, n_iters: int,
                 and it % refresh_every == 0):
             consts = _make_consts_jit(net, jnp.float32(costs[-1]),
                                       min_scale)
-        mask_d = mask_r = None
-        if async_frac > 0.0 and rng is not None:
-            rng, k1, k2 = jax.random.split(rng, 3)
-            mask_d = jax.random.bernoulli(k1, 1.0 - async_frac, (net.S, net.V))
-            mask_r = jax.random.bernoulli(k2, 1.0 - async_frac, (net.S, net.V))
-        out = sgp_step_flows(
-            net, phi, fl, consts, variant=variant, beta=beta,
-            mask_data=mask_d, mask_result=mask_r,
-            allowed_data=allowed_data, allowed_result=allowed_result,
-            method=method, use_blocking=use_blocking, scaling=scaling,
-            sigma=jnp.float32(sigma), kappa=kappa, proj_impl=proj_impl,
-            engine_impl=engine_impl, nbrs=nbrs, buckets=state.buckets,
-            with_aux=callback is not None, active=state.active)
-        phi_new, fl_new, cost_new = out[:3]
+        carry = (jnp.float32(sigma), jnp.float32(costs[-1])) + idle
+        out, _, (phi_new, fl_new, cost_new) = _dispatch_block(
+            net, phi, fl, consts, carry, 1, opts,
+            rng=rng if use_rng else None, active=state.active)
+        if use_rng:
+            rng = out[8]
         obs.count("host_syncs")
         new_cost = float(cost_new)   # the host driver's per-iteration sync
         accepted, sigma, stop = accept_step(new_cost, costs[-1], sigma,
@@ -1056,7 +1162,10 @@ def run_chunk(net: CECNetwork, state: RunState, n_iters: int,
             # would report it (its cost IS the last accepted cost;
             # "flows" is the driver's FlowsCarry slice)
             aux = {"cost": jnp.float32(costs[-1]), "flows": fl,
-                   "marginals": out[3]}
+                   "marginals": _marginals_jit(
+                       net, phi, fl, method, nbrs=nbrs,
+                       engine_impl=engine_impl,
+                       slot_F=method == "sparse", buckets=state.buckets)}
         if not accepted:
             n_rejected += 1
             if stop:
@@ -1077,22 +1186,36 @@ def run_chunk(net: CECNetwork, state: RunState, n_iters: int,
     return state
 
 
+def _flat_histories(hists, counts):
+    """Fetched per-dispatch histories as per-iteration host arrays: the
+    first `k` slots of each ([BLOCK] buffers of a block, scalars of a
+    single-iteration dispatch), concatenated in dispatch order."""
+    return tuple(
+        np.concatenate([np.reshape(h, -1)[:k] for h, k in zip(hist, counts)])
+        if counts else np.zeros((0,)) for hist in hists)
+
+
 def _fold_fused_histories(state, sigma, n_rej, stopped, cost_hist,
-                          take_hist, live_hist, extra=None):
+                          take_hist, live_hist, extra=None, counts=None):
     """The fused chunk's single device→host sync + bookkeeping
     writeback, shared by both drivers (`_run_chunk_fused`,
     `distributed._run_distributed_chunk_fused`) so the
     accept_step-mirroring accounting — which executed-and-accepted
     iterations append to `costs`, how `it` advances, when `stopped`
-    latches — stays single-sourced.  `extra` is any additional device
-    pytree to fetch in the SAME device_get (the guard layer's sentinel
-    histories); the fetched host histories come back as
+    latches — stays single-sourced.  `counts` (one per dispatch) says
+    how many iterations each history entry holds (`_flat_histories`);
+    None means one scalar per iteration.  `extra` is any additional
+    device pytree to fetch in the SAME device_get (the guard layer's
+    sentinel histories); the fetched host histories come back as
     (cost_hist, take_hist, live_hist, extra) so callers can render
     per-iteration records without a second sync."""
     obs.count("host_syncs")
     sigma, n_rej, stopped, cost_hist, take_hist, live_hist, extra = \
         jax.device_get((sigma, n_rej, stopped, cost_hist, take_hist,
                         live_hist, extra))
+    if counts is not None:
+        cost_hist, take_hist, live_hist = _flat_histories(
+            (cost_hist, take_hist, live_hist), counts)
     for c, t, l in zip(cost_hist, take_hist, live_hist):
         if l and t:
             state.costs.append(float(c))
@@ -1130,9 +1253,16 @@ class FusedStream:
     `Neighbors` tile shapes and must break the stream (finish, apply
     through the event loop, start a new stream).
 
-    A stopped carry (sigma blow-up / tol exit) keeps dispatching frozen
-    no-ops whose outputs are discarded — the event loop's early return,
-    expressed as selects — and the next `rebaseline` un-freezes it, as
+    Iterations run as `_sgp_block` dispatches: up to BLOCK iterations
+    per dispatch (paper scaling: up to the next consts refresh; a fault
+    state and the async masks' rng ride the block's carry), one per
+    dispatch — the block's candidate plus a separate guarded select —
+    under guards, whose checkpoint cadence follows the host's iteration
+    count.
+
+    A stopped carry (sigma blow-up / tol exit) ends a block's loop, and
+    later dispatches run no pass — the event loop's early return,
+    expressed on device — and the next `rebaseline` un-freezes it, as
     `apply_event`'s fresh state does.
     """
 
@@ -1152,14 +1282,15 @@ class FusedStream:
                 "init_run_state (or ReplayEngine(rng=...))")
         self.net = net
         self.state = state
-        self._o = dict(variant=variant, beta=beta,
-                       allowed_data=allowed_data,
-                       allowed_result=allowed_result,
-                       async_frac=async_frac, use_blocking=use_blocking,
-                       refresh_every=refresh_every, scaling=scaling,
-                       kappa=kappa, proj_impl=proj_impl,
-                       engine_impl=engine_impl)
-        self._adaptive = scaling == "adaptive" and variant == "sgp"
+        self._refresh_every = refresh_every
+        self._opts = _block_opts(state, variant=variant, beta=beta,
+                                 allowed_data=allowed_data,
+                                 allowed_result=allowed_result,
+                                 async_frac=async_frac,
+                                 use_blocking=use_blocking, scaling=scaling,
+                                 kappa=kappa, proj_impl=proj_impl,
+                                 engine_impl=engine_impl)
+        self._adaptive = self._opts["adaptive"]
         self._refresh = scaling == "paper" and refresh_every
         self._use_rng = async_frac > 0.0 and state.rng is not None
         self._faulted = (state.fault_plan is not None
@@ -1181,7 +1312,9 @@ class FusedStream:
         self._n_rej = jnp.asarray(0, jnp.int32)
         self._stopped = jnp.asarray(bool(state.stopped))
         self._tol32 = jnp.float32(tol)
-        self._cost_h, self._take_h, self._live_h = [], [], []
+        # per dispatch: its candidate cost / take / live history and
+        # how many iterations it was given
+        self._cost_h, self._take_h, self._live_h, self._n_h = [], [], [], []
         self._code_h, self._roll_h, self._ck_h = [], [], []
         self._it = state.it           # per-segment iteration counter
         self._seg_it0 = state.it      # `it` the open segment began at
@@ -1191,84 +1324,85 @@ class FusedStream:
     # ----------------------------------------------------------- advance
     def advance(self, n_iters: int) -> "FusedStream":
         """Dispatch `n_iters` driver iterations asynchronously — python
-        never blocks on a device value.  Each iteration is the shared
-        `sgp_step_flows` executable plus the `_accept_update` (or
-        guarded) select kernel; candidate costs and accepted/executed
-        flags accumulate as device scalars for `finish`."""
+        never blocks on a device value.  Candidate costs and
+        accepted/executed flags accumulate as device histories for
+        `finish`."""
         assert not self._finished, "stream already finished"
-        net, state, o = self.net, self.state, self._o
+        every = self._refresh_every
         obs.count("sgp.iterations", n_iters)
         with obs.span("sgp.advance"):
-            for it in range(self._it, self._it + n_iters):
-                if self._refresh and it > 0 and it % o["refresh_every"] == 0:
-                    fresh = _make_consts_jit(net, self._prev, state.min_scale)
+            it, end = self._it, self._it + n_iters
+            while it < end:
+                if self._refresh and it > 0 and it % every == 0:
+                    fresh = _make_consts_jit(self.net, self._prev,
+                                             self.state.min_scale)
                     stopped = self._stopped
                     self._consts = jax.tree.map(
                         lambda old, new: jnp.where(stopped, old, new),
                         self._consts, fresh)
-                mask_d = mask_r = rng_new = None
-                if self._use_rng:
-                    rng_new, k1, k2 = jax.random.split(self._rng, 3)
-                    mask_d = jax.random.bernoulli(k1, 1.0 - o["async_frac"],
-                                                  (net.S, net.V))
-                    mask_r = jax.random.bernoulli(k2, 1.0 - o["async_frac"],
-                                                  (net.S, net.V))
-                with obs.span("sgp.step"):
-                    out = sgp_step_flows(
-                        net, self._phi, self._fl, self._consts,
-                        variant=o["variant"], beta=o["beta"],
-                        mask_data=mask_d, mask_result=mask_r,
-                        allowed_data=o["allowed_data"],
-                        allowed_result=o["allowed_result"],
-                        method=state.method, use_blocking=o["use_blocking"],
-                        scaling=o["scaling"], sigma=self._sigma,
-                        kappa=o["kappa"],
-                        proj_impl=o["proj_impl"], engine_impl=o["engine_impl"],
-                        nbrs=state.nbrs, buckets=state.buckets,
-                        fault_plan=state.fault_plan, fault_state=self._fs,
-                        active=self._active)
-                stopped_pre = self._stopped
-                if self._faulted:
-                    phi_new, fl_new, cost_new, fs_new = out
-                    # a stopped carry freezes the fault state too, so
-                    # chunked resumption past a stop stays bitwise (the
-                    # dead dispatches must not advance the fault rng/ring)
-                    self._fs = jax.tree.map(
-                        lambda new, old: jnp.where(stopped_pre, old, new),
-                        fs_new, self._fs)
-                else:
-                    phi_new, fl_new, cost_new = out
-                with obs.span("sgp.accept"):
-                    if self._guarded:
-                        cfg = state.guard_cfg
-                        do_ckpt = bool(cfg.checkpoint_every
-                                       and it % cfg.checkpoint_every == 0)
-                        (self._phi, self._fl, self._sigma, self._prev,
-                         self._n_costs, self._n_rej, self._stopped, self._rng,
-                         take, live, self._gs, code, rolled, ck_cost) = \
-                            self._guarded_update(
-                                phi_new, fl_new, cost_new, self._phi, self._fl,
-                                self._sigma, self._prev, self._n_costs,
-                                self._n_rej, self._stopped, rng_new, self._rng,
-                                self._tol32, self._gs, state.nbrs,
-                                adaptive=self._adaptive, cfg=cfg,
-                                do_ckpt=do_ckpt)
-                        self._code_h.append(code)
-                        self._roll_h.append(rolled)
-                        self._ck_h.append(ck_cost)
-                    else:
-                        (self._phi, self._fl, self._sigma, self._prev,
-                         self._n_costs, self._n_rej, self._stopped, self._rng,
-                         take, live) = _accept_update(
-                            phi_new, fl_new, cost_new, self._phi, self._fl,
-                            self._sigma, self._prev, self._n_costs,
-                            self._n_rej, self._stopped, rng_new, self._rng,
-                            self._tol32, adaptive=self._adaptive)
-                self._cost_h.append(cost_new)
-                self._take_h.append(take)
-                self._live_h.append(live)
-        self._it += n_iters
+                if self._guarded:
+                    self._iterate(it)
+                    it += 1
+                    continue
+                n = min(end - it, BLOCK)
+                if self._refresh:
+                    n = min(n, every - it % every)
+                with obs.span("sgp.block"):
+                    carry, hist, _ = self._dispatch(n)
+                (self._phi, self._fl, self._sigma, self._prev,
+                 self._n_costs, self._n_rej, self._stopped, fs, rng) = carry
+                self._fs = fs if self._faulted else self._fs
+                self._rng = rng if self._use_rng else self._rng
+                self._push(n, *hist)
+                it += n
+        self._it = end
         return self
+
+    def _dispatch(self, n: int):
+        """One `_sgp_block` dispatch of `n` iterations from the stream's
+        carry, its fault state and its rng."""
+        return _dispatch_block(
+            self.net, self._phi, self._fl, self._consts,
+            (self._sigma, self._prev, self._n_costs, self._n_rej,
+             self._stopped, self._tol32), n, self._opts,
+            fault_state=self._fs if self._faulted else None,
+            rng=self._rng if self._use_rng else None, active=self._active)
+
+    def _push(self, n, cost, take, live):
+        self._cost_h.append(cost)
+        self._take_h.append(take)
+        self._live_h.append(live)
+        self._n_h.append(n)
+
+    def _iterate(self, it: int):
+        """One guarded iteration: the block's candidate at n=1, then the
+        guarded select on the stream's own carry (the sentinels and the
+        checkpoint cadence need the host's iteration number)."""
+        state = self.state
+        with obs.span("sgp.step"):
+            carry, _, (phi_new, fl_new, cost_new) = self._dispatch(1)
+        # a stopped carry runs no pass, so the fault state and the rng
+        # stay frozen too and chunked resumption past a stop stays
+        # bitwise (the dead dispatches must not advance them)
+        self._fs = carry[7] if self._faulted else self._fs
+        rng_new = carry[8] if self._use_rng else None
+        cfg = state.guard_cfg
+        do_ckpt = bool(cfg.checkpoint_every
+                       and it % cfg.checkpoint_every == 0)
+        with obs.span("sgp.accept"):
+            (self._phi, self._fl, self._sigma, self._prev,
+             self._n_costs, self._n_rej, self._stopped, self._rng,
+             take, live, self._gs, code, rolled, ck_cost) = \
+                self._guarded_update(
+                    phi_new, fl_new, cost_new, self._phi, self._fl,
+                    self._sigma, self._prev, self._n_costs,
+                    self._n_rej, self._stopped, rng_new, self._rng,
+                    self._tol32, self._gs, state.nbrs,
+                    adaptive=self._adaptive, cfg=cfg, do_ckpt=do_ckpt)
+        self._code_h.append(code)
+        self._roll_h.append(rolled)
+        self._ck_h.append(ck_cost)
+        self._push(1, cost_new, take, live)
 
     # -------------------------------------------------------- rebaseline
     def rebaseline(self, net_new: CECNetwork, repair=None, *,
@@ -1299,9 +1433,9 @@ class FusedStream:
         phi = self._phi if repair is None else repair(self._phi)
         fl, T0 = flows_carry_and_cost_jit(
             net_new, phi, state.method, nbrs=state.nbrs,
-            engine_impl=self._o["engine_impl"], buckets=state.buckets)
+            engine_impl=self._opts["engine_impl"], buckets=state.buckets)
         self._markers.append(dict(
-            end=len(self._cost_h), it0=self._seg_it0,
+            end=sum(self._n_h), it0=self._seg_it0,
             prev=self._prev, n_rej=self._n_rej, T0=T0))
         self.net = net_new
         self._phi, self._fl = phi, fl
@@ -1321,7 +1455,7 @@ class FusedStream:
             self._fs = init_fault_state(
                 net_new, phi, fl, state.fault_plan, rng=fault_rng,
                 method=state.method, nbrs=state.nbrs,
-                engine_impl=self._o["engine_impl"], buckets=state.buckets)
+                engine_impl=self._opts["engine_impl"], buckets=state.buckets)
         if state.guard_cfg is not None:
             from .guards import init_guard_state
             self._gs = init_guard_state(phi, fl, T0, state.guard_cfg)
@@ -1374,7 +1508,8 @@ class FusedStream:
         if not self._markers:
             cost_h, _, live_h, extra_h = _fold_fused_histories(
                 state, self._sigma, self._n_rej, self._stopped,
-                self._cost_h, self._take_h, self._live_h, extra)
+                self._cost_h, self._take_h, self._live_h, extra,
+                counts=self._n_h)
             if self._guarded:
                 state.guard_events.extend(self._render_guard_events(
                     extra_h, cost_h, live_h, 0, len(cost_h),
@@ -1393,6 +1528,8 @@ class FusedStream:
             self._sigma, self._n_rej, self._stopped, self._cost_h,
             self._take_h, self._live_h, extra,
             [(m["prev"], m["n_rej"], m["T0"]) for m in self._markers]))
+        cost_h, take_h, live_h = _flat_histories((cost_h, take_h, live_h),
+                                                 self._n_h)
         bounds = [0] + [m["end"] for m in self._markers] + [len(cost_h)]
         it0s = [m["it0"] for m in self._markers] + [self._seg_it0]
         segs = []
@@ -1439,19 +1576,19 @@ def _run_chunk_fused(net: CECNetwork, state: RunState, fl, n_iters: int,
                      kappa: float, proj_impl: Optional[str],
                      engine_impl: Optional[str]) -> RunState:
     """The whole accept/reject loop with ZERO host syncs inside: an
-    async pipeline of the SAME compiled step the python reference runs.
+    async pipeline of the SAME compiled block the python reference runs.
 
-    One `FusedStream` segment, advanced `n_iters` and finished — the
-    per-iteration candidate costs and accepted/executed flags accumulate
-    as device scalars and come back in ONE `device_get` after the last
-    dispatch, the chunk's single device→host sync.  Because the step
-    executable is literally the host loop's jit-cache entry and the
-    select arithmetic mirrors `accept_step`'s f32 ops, the resulting
+    One `FusedStream` segment, advanced `n_iters` and finished — on the
+    plain path one `_sgp_block` dispatch per BLOCK iterations, whose
+    candidate costs and accepted/executed flags come back in ONE
+    `device_get` after the last dispatch, the chunk's single
+    device→host sync.  Because the block executable is literally the
+    host loop's jit-cache entry (dynamic iteration count) and the select
+    arithmetic mirrors `accept_step`'s f32 ops, the resulting
     `costs`/sigma/rng/φ trajectory is bitwise identical to the python
     loop (locked by tests/test_fused_driver.py).  A mid-chunk stop
-    (sigma blow-up / tol) freezes the carry on device; the remaining
-    pipelined iterations are discarded no-ops, so prefer right-sizing
-    chunks when stops are expected.
+    (sigma blow-up / tol) ends the block's loop on device; the later
+    dispatches of the chunk run no pass.
     """
     with obs.span("sgp.open"):
         stream = FusedStream(net, state, fl=fl, variant=variant, beta=beta,

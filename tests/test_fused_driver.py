@@ -5,11 +5,13 @@ counterparts) produce BITWISE-identical trajectories under
 driver="fused" and driver="host" — costs list, accept/reject sequence,
 sigma safeguard, n_rejected, async rng threading, tol early exit, final
 φ.  This holds by construction (both drivers dispatch the same compiled
-`sgp_step_flows` executable and the fused `_accept_update` select
-mirrors `accept_step`'s f32 arithmetic op-for-op), and these tests lock
-it on every Table II scenario — including rows whose adaptive runs
-naturally REJECT steps — plus a crafted instance that rejects every
-step and stops on the sigma blow-up.
+`_sgp_block` executable — the host loop one iteration at a time — and
+the block's `_accept_update_impl` select mirrors `accept_step`'s f32
+arithmetic op-for-op), and these tests lock it on every Table II
+scenario — including rows whose adaptive runs naturally REJECT steps —
+plus a crafted instance that rejects every step and stops on the sigma
+blow-up.  The fused trajectory does not depend on how the iterations
+are split into block dispatches.
 
 Also locked here: the batched recursion stacking (`_taint_pair_sparse`
 / `_max_path_len_pair_sparse` bitwise the unstacked solves), the
@@ -28,7 +30,8 @@ from repro import core
 from repro.core.marginals import compute_marginals
 from repro.core.network import (FlowsCarry, flows_carry_and_cost,
                                 _phi_edge_views)
-from repro.core.sgp import (SUPPORT_TOL, _max_path_len_pair_sparse,
+from repro.core.sgp import (BLOCK, SUPPORT_TOL, FusedStream,
+                            _max_path_len_pair_sparse,
                             _max_path_len_sparse, _sgp_propose_impl,
                             _taint_pair_sparse, _taint_sparse,
                             init_run_state, make_consts, run_chunk)
@@ -163,6 +166,52 @@ def test_tol_only_fires_after_accepted_step(driver):
     assert st.stopped
     assert st.n_rejected == 20           # sigma blow-up, NOT a tol stop
     assert st.it == 20
+
+
+# ---------------------------------------------- block-size independence
+N_BLOCKED = BLOCK + 44     # past one block: chunks of BLOCK, BLOCK + 1 split
+
+
+def _summary(st):
+    return (st.costs, st.sigma, st.n_rejected, st.it, st.stopped)
+
+
+@pytest.mark.parametrize("case,chunk", [
+    ("plain", 1), ("plain", 7), ("plain", 64), ("plain", BLOCK),
+    ("plain", BLOCK + 1), ("tol_exit", 5), ("sigma_blowup", 7)])
+def test_block_size_independence(case, chunk):
+    """One fused stream advanced in chunks of `chunk` iterations (each
+    advance split into block dispatches of at most BLOCK) walks the
+    trajectory of the uninterrupted fused run bitwise; a tol exit and a
+    sigma blow-up that stop inside a block match the host driver."""
+    if case == "plain":
+        net, phi0 = _setup("geant")     # rejects steps under adaptive
+        n, kw = N_BLOCKED, {}
+
+        def fresh():
+            return init_run_state(net, phi0, method="sparse")
+
+        ref = run_chunk(net, fresh(), n, driver="fused")
+    else:
+        net, phi0 = _setup("abilene")
+        n = 40
+        kw = dict(tol=1e-3) if case == "tol_exit" else {}
+        if case == "tol_exit":
+            def fresh():
+                return init_run_state(net, phi0, method="sparse")
+        else:
+            def fresh():
+                return _nan_state(net)
+        ref = run_chunk(net, fresh(), n, driver="host", **kw)
+        assert ref.stopped and ref.it % chunk != 0   # inside a block
+    st = fresh()
+    stream = FusedStream(net, st, **kw)
+    for start in range(0, n, chunk):
+        stream.advance(min(chunk, n - start))
+    stream.finish()
+    assert _summary(st) == _summary(ref)
+    for a, b in zip(jax.tree.leaves(st.phi), jax.tree.leaves(ref.phi)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ------------------------------------------------------------- replay

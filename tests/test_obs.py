@@ -1,5 +1,6 @@
 """repro.obs: host spans and counters, off by default, and the driver's
 and seeding's spans and counters on a real solve."""
+import math
 import time
 
 import jax
@@ -7,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro import core, obs
+from repro.core.sgp import BLOCK
 
 
 @pytest.fixture(autouse=True)
@@ -73,24 +75,27 @@ def test_run_spans_counters_and_bitwise_result():
     assert calls == {"sgp.run": 1, "sgp.init": 1, "seed.neighbors": 1,
                      "sgp.init.flows": 1, "sgp.init.sync": 1,
                      "sgp.init.consts": 1, "sgp.open": 1, "sgp.advance": 1,
-                     "sgp.step": 5, "sgp.accept": 5, "sgp.finish": 1}
+                     "sgp.block": 1, "sgp.finish": 1}
     # the adjacency pull of build_neighbors, float(T0), finish's device_get
-    assert snap["counters"] == {"sgp.iterations": 5, "host_syncs": 3}
+    assert snap["counters"] == {"sgp.iterations": 5, "sgp.blocks": 1,
+                                "host_syncs": 3}
     spans = snap["spans"]
     inside_run = sum(spans[k]["total_s"] for k in
                      ("sgp.init", "sgp.open", "sgp.advance", "sgp.finish"))
     assert spans["sgp.run"]["self_s"] == pytest.approx(
         spans["sgp.run"]["total_s"] - inside_run, abs=1e-12)
     assert spans["sgp.advance"]["self_s"] == pytest.approx(
-        spans["sgp.advance"]["total_s"] - spans["sgp.step"]["total_s"]
-        - spans["sgp.accept"]["total_s"], abs=1e-12)
+        spans["sgp.advance"]["total_s"] - spans["sgp.block"]["total_s"],
+        abs=1e-12)
     assert hist_on["costs"] == hist_off["costs"]
     for a, b in zip(jax.tree.leaves(phi_on), jax.tree.leaves(phi_off)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_cold_solve_host_syncs():
-    """Seeding and solve as one cold solve: 9 device-to-host fetches."""
+@pytest.mark.parametrize("n_iters", [3, 200])
+def test_cold_solve_host_syncs(n_iters):
+    """Seeding and solve as one cold solve: 9 device-to-host fetches,
+    and one block dispatch per BLOCK iterations."""
     net, _ = _geant()
     obs.enable()
     nbrs = core.build_neighbors(net.adj)        # adjacency
@@ -98,13 +103,63 @@ def test_cold_solve_host_syncs():
     # out_mask
     phi0 = core.spt_phi_sparse(net, nbrs)
     seeded = obs.snapshot()
-    core.run(net, phi0, n_iters=3, method="sparse")
+    core.run(net, phi0, n_iters=n_iters, method="sparse")
     obs.disable()
     snap = obs.snapshot()
     assert seeded["counters"] == {"host_syncs": 6}
     assert {k: v["calls"] for k, v in seeded["spans"].items()} == {
         "seed.neighbors": 1, "seed.next_hops": 1}
     assert snap["counters"]["host_syncs"] == 9
+    assert snap["counters"]["sgp.blocks"] == math.ceil(n_iters / BLOCK)
+    assert snap["spans"]["sgp.block"]["calls"] == math.ceil(n_iters / BLOCK)
+
+
+@pytest.mark.parametrize("arm", ["guards", "faults"])
+def test_per_iteration_path_spans(arm):
+    """Guards feed the carry per iteration, alone or with a fault plan
+    riding it: one block dispatch of one iteration (`sgp.step`) and one
+    guarded select (`sgp.accept`) an iteration, and still one fetch at
+    the end."""
+    net, phi0 = _geant()
+    kw = {"guards": core.GuardConfig()}
+    if arm == "faults":
+        kw["fault_plan"] = core.FaultPlan(participation_p=0.9)
+    obs.enable()
+    core.run(net, phi0, n_iters=5, method="sparse", **kw)
+    obs.disable()
+    snap = obs.snapshot()
+    spans = snap["spans"]
+    assert spans["sgp.step"]["calls"] == 5
+    assert spans["sgp.accept"]["calls"] == 5
+    assert "sgp.block" not in spans
+    assert snap["counters"]["sgp.blocks"] == 5
+    assert snap["counters"]["sgp.iterations"] == 5
+    assert spans["sgp.advance"]["self_s"] == pytest.approx(
+        spans["sgp.advance"]["total_s"] - spans["sgp.step"]["total_s"]
+        - spans["sgp.accept"]["total_s"], abs=1e-12)
+    assert spans["sgp.finish"]["calls"] == 1
+
+
+@pytest.mark.parametrize("arm", ["faults", "async"])
+def test_block_path_arms(arm):
+    """A fault plan and the Theorem-2 async masks ride the block's
+    carry: one block dispatch per BLOCK iterations, no per-iteration
+    spans, one fetch at the end."""
+    net, phi0 = _geant()
+    kw = ({"fault_plan": core.FaultPlan(participation_p=0.9)}
+          if arm == "faults"
+          else {"rng": jax.random.PRNGKey(3), "async_frac": 0.3})
+    n_iters = 5
+    obs.enable()
+    core.run(net, phi0, n_iters=n_iters, method="sparse", **kw)
+    obs.disable()
+    snap = obs.snapshot()
+    spans = snap["spans"]
+    assert "sgp.step" not in spans and "sgp.accept" not in spans
+    assert snap["counters"]["sgp.blocks"] == math.ceil(n_iters / BLOCK)
+    assert spans["sgp.block"]["calls"] == math.ceil(n_iters / BLOCK)
+    assert snap["counters"]["sgp.iterations"] == n_iters
+    assert spans["sgp.finish"]["calls"] == 1
 
 
 def test_host_driver_counts_each_cost_fetch():
@@ -114,5 +169,6 @@ def test_host_driver_counts_each_cost_fetch():
     obs.disable()
     snap = obs.snapshot()
     # build_neighbors' adjacency, float(T0), one float(cost) an iteration
-    assert snap["counters"] == {"sgp.iterations": 4, "host_syncs": 6}
+    assert snap["counters"] == {"sgp.iterations": 4, "sgp.blocks": 4,
+                                "host_syncs": 6}
     assert "sgp.advance" not in snap["spans"]

@@ -24,7 +24,7 @@ import pytest
 from repro import core
 from repro.core.network import flows_carry_and_cost_jit
 from repro.core.replay import check_feasible
-from repro.core.sgp import sgp_step_flows
+from repro.core.sgp import _sgp_block
 
 
 def _setup(name="sw_queue"):
@@ -183,11 +183,11 @@ class TestTaskChurn:
         eng.iterate(4)
         eng.apply_event(_arrival(net, seed=0))
         eng.iterate(4)                               # caches fully warm
-        n_step = sgp_step_flows._cache_size()
+        n_step = _sgp_block._cache_size()
         n_flows = flows_carry_and_cost_jit._cache_size()
         eng.apply_event(_arrival(net, seed=1))
         eng.iterate(4)
-        assert sgp_step_flows._cache_size() == n_step
+        assert _sgp_block._cache_size() == n_step
         assert flows_carry_and_cost_jit._cache_size() == n_flows
 
     def test_arrival_departure_loop(self):

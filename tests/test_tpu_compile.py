@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import core
 from repro.core.network import flows_carry_and_cost
 from repro.core.scenarios import _mk_adj
-from repro.core.sgp import sgp_step_flows
+from repro.core.sgp import BLOCK, _sgp_block, sgp_step_flows
 from repro.kernels import ops
 
 V5E_HBM = 16e9                 # bytes of device memory on one v5e chip
@@ -145,3 +145,33 @@ def test_sgp_step_flows_sw_1000(chip, sw_1000):
 
     compiled = _compile(step, *args, sigma)
     assert "tpu_custom_call" in compiled.as_text()   # the QP kernel
+
+
+def test_sgp_block_sw_1000(chip, sw_1000):
+    """The fused driver's block — the step and the accept select in an
+    on-device loop of up to BLOCK iterations — at `sw_1000` widths."""
+    net, nbrs, phi = sw_1000
+    fl, T0 = jax.eval_shape(
+        lambda n, p: flows_carry_and_cost(n, p, "sparse", nbrs=nbrs),
+        net, phi)
+    consts = jax.eval_shape(core.make_consts, net, T0)
+    args = _abstract((net, phi, fl, consts, nbrs), chip)
+    f32 = jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    flag = jax.ShapeDtypeStruct((), jnp.bool_, sharding=chip)
+    engine = ops.default_impl("edge_rounds", "tpu")
+    proj = ops.default_impl("simplex_project", "tpu")
+
+    def block(net, phi, fl, consts, nbrs, sigma, prev, n_costs, n_rej,
+              stopped, tol, n):
+        return _sgp_block(
+            net, phi, fl, consts, sigma, prev, n_costs, n_rej, stopped,
+            tol, n, step=sgp_step_flows, adaptive=True, method="sparse",
+            kappa=0.0, engine_impl=engine, proj_impl=proj, nbrs=nbrs)
+
+    compiled = _compile(block, *args, f32, f32, i32, i32, flag, f32, i32)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                  # the QP kernel
+    assert "while" in text                            # the block's loop
+    assert jax.eval_shape(block, *args, f32, f32, i32, i32, flag, f32,
+                          i32)[1][0].shape == (BLOCK,)
