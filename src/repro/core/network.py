@@ -1074,19 +1074,23 @@ def refeasibilize(net: CECNetwork, phi: Phi,
     destination on the NEW graph (spreading over all out-edges can close
     a loop and make the traffic solve singular).
 
-    Rows that were ALREADY empty before the change — a recovered node
-    rejoining with no routing yet, padding tasks — are left empty when
-    they carry no result traffic on the repaired strategy (no surviving
-    row forwards to them and they compute no direct input), so they are
-    feasible as-is, and the next SGP step grows them a row through the
-    loop-protected blocked-set protocol.  This is what lets a
-    failure→recovery roundtrip keep the warm iterate instead of
-    resetting every task to the SPT tree.  An empty row that WILL carry
-    result traffic immediately — the node locally computes restored
+    No node that is alive (has an exit on the new graph) and is not the
+    task's destination keeps an empty result row.  A row that was
+    ALREADY empty before the change — a recovered node rejoining, whose
+    edges all come back as zero-mass slots — takes its shortest-path
+    row on the new graph, and every other row of the task keeps its
+    mass.  The SGP step does not grow such a row back: a node with an
+    empty row reads as a free sink (zero result marginal), so the
+    blocked-set rule blocks its exits while its neighbours' steps start
+    routing results into it, and those results are dropped.  The fill
+    closes no loop: no surviving row carries mass into a node whose row
+    was empty (that flow would already have been lost), and the filled
+    rows among themselves follow one shortest-path tree.  So a
+    failure→recovery roundtrip keeps the warm iterate instead of
+    resetting every task to the SPT tree.  An empty row that carries
+    result traffic at once — the node locally computes restored
     exogenous input (r·φ_local > 0, a > 0), as a recovered source node
-    does — still counts as damage: leaving it empty would silently drop
-    that result flow from the objective (understating cost and making
-    the driver reject the step that repairs it).
+    does — still counts as damage and rebuilds its whole task.
 
     rebuild_tasks : optional [S] bool — tasks to force-rebuild from the
     new graph's SPT regardless of damage (e.g. a destination re-draw,
@@ -1129,7 +1133,7 @@ def refeasibilize(net: CECNetwork, phi: Phi,
         broken = broken | rebuild_tasks
     spt = spt_phi(net).result
     result = result / jnp.maximum(rsum[..., None], 1e-30)
-    result = jnp.where(rsum[..., None] > 1e-12, result, 0.0)
+    result = jnp.where(rsum[..., None] > 1e-12, result, spt)
     result = jnp.where(broken[:, None, None], spt, result)
     result = jnp.where(is_dest[..., None], 0.0, result)
     return Phi(data, result)
@@ -1182,6 +1186,53 @@ def _slot_remap(old: Neighbors, new: Neighbors):
     return jnp.asarray(np.maximum(remap, 0)), jnp.asarray(valid)
 
 
+def _repair_slots_impl(phi_sp: PhiSparse, old_mask, remap, valid,
+                       new_mask, r, a, dest, spt_sp, rebuild_tasks):
+    """The arithmetic of `refeasibilize_sparse`: `phi_sp` (aligned to
+    slots `old_mask`) re-slotted by `remap`/`valid` (`_slot_remap`)
+    onto the slots `new_mask`, repaired with the SPT rows `spt_sp`."""
+    idx_i = jnp.arange(old_mask.shape[0])[:, None]
+
+    def reslot(x_sp):
+        moved = x_sp[:, idx_i, remap]                      # [S, V, Dmax_new]
+        return jnp.where(valid, moved, jnp.zeros((), x_sp.dtype))
+
+    def mask(x_sp):
+        return jnp.where(old_mask, x_sp, jnp.zeros((), x_sp.dtype))
+
+    data = reslot(mask(phi_sp.data))
+    local = phi_sp.local[..., 0]
+    dsum = jnp.sum(data, axis=-1) + local
+    # missing mass goes to local offload
+    local = local + jnp.maximum(0.0, 1.0 - dsum)
+    tot = jnp.maximum(jnp.sum(data, axis=-1) + local, 1e-30)
+    data = data / tot[..., None]
+    local = local / tot
+
+    result_masked = mask(phi_sp.result)
+    result = reslot(result_masked)
+    rsum = jnp.sum(result, axis=-1)                        # [S, V]
+    rsum_before = jnp.sum(result_masked, axis=-1)
+    V = old_mask.shape[0]
+    is_dest = (jnp.arange(V)[None] == dest[:, None])       # [S, V]
+    # same damaged-row policy as the dense path (see refeasibilize)
+    alive = jnp.any(new_mask, axis=-1)[None] | is_dest
+    src = (r * local > 1e-12) & (a[:, None] > 0.0)
+    damaged = (rsum <= 1e-12) & ((rsum_before > 1e-12) | src) \
+        & ~is_dest & alive
+    broken = jnp.any(damaged, axis=-1)                     # [S]
+    if rebuild_tasks is not None:
+        broken = broken | rebuild_tasks
+    result = result / jnp.maximum(rsum[..., None], 1e-30)
+    result = jnp.where(rsum[..., None] > 1e-12, result, spt_sp)
+    result = jnp.where(broken[:, None, None], spt_sp, result)
+    result = jnp.where(is_dest[..., None], 0.0, result)
+    return PhiSparse(data, local[..., None], result)
+
+
+_repair_slots = jax.jit(_repair_slots_impl)
+
+
 def refeasibilize_sparse(net: CECNetwork, phi_sp: PhiSparse,
                          nbrs: Neighbors,
                          rebuild_tasks: jnp.ndarray | None = None
@@ -1195,53 +1246,26 @@ def refeasibilize_sparse(net: CECNetwork, phi_sp: PhiSparse,
     missing data mass to local offload, any task whose result routing
     LOST mass rebuilt entirely from the new graph's shortest-path tree
     (partial repair can close a loop), rows that were already empty —
-    recovered nodes rejoining after a failure — left empty so the warm
-    iterate survives a failure→recovery roundtrip (`_slot_remap` handles
-    growing neighborhoods: restored edges come back as zero-mass slots),
-    UNLESS the empty row locally computes restored exogenous input and
-    would silently drop its result flow (see `refeasibilize`).
+    recovered nodes rejoining after a failure — given their
+    shortest-path row while the task's other rows keep their mass, so
+    the warm iterate survives a failure→recovery roundtrip and no alive
+    non-destination row is left empty to drop results (`_slot_remap`
+    handles growing neighborhoods: restored edges come back as
+    zero-mass slots), and a task whose empty row locally computes
+    restored exogenous input rebuilt whole (see `refeasibilize`).
     `rebuild_tasks` force-rebuilds specific tasks from the SPT (see
     `refeasibilize`).  All slot-level including the SPT fallback rows
     (`spt_result_slots` writes the one-hots natively), so churn replay
-    never materializes a dense [S, V, V] array even at V=10⁴.
+    never materializes a dense [S, V, V] array even at V=10⁴; the
+    arithmetic is one jitted dispatch (`_repair_slots`), where eager
+    operations cost a host dispatch each.
     """
     new_nbrs = build_neighbors(net.adj)
     remap, valid = _slot_remap(nbrs, new_nbrs)
-    idx_i = jnp.arange(net.V)[:, None]
-
-    def reslot(x_sp):
-        moved = x_sp[:, idx_i, remap]                      # [S, V, Dmax_new]
-        return jnp.where(valid, moved, jnp.zeros((), x_sp.dtype))
-
-    data = reslot(mask_slots(phi_sp.data, nbrs))
-    local = phi_sp.local[..., 0]
-    dsum = jnp.sum(data, axis=-1) + local
-    # missing mass goes to local offload
-    local = local + jnp.maximum(0.0, 1.0 - dsum)
-    tot = jnp.maximum(jnp.sum(data, axis=-1) + local, 1e-30)
-    data = data / tot[..., None]
-    local = local / tot
-
-    result_masked = mask_slots(phi_sp.result, nbrs)
-    result = reslot(result_masked)
-    rsum = jnp.sum(result, axis=-1)                        # [S, V]
-    rsum_before = jnp.sum(result_masked, axis=-1)
-    S, V = net.S, net.V
-    is_dest = (jnp.arange(V)[None] == net.dest[:, None])   # [S, V]
-    # same damaged-row policy as the dense path (see refeasibilize)
-    alive = jnp.any(new_nbrs.out_mask, axis=-1)[None] | is_dest
-    src = (net.r * local > 1e-12) & (net.a[:, None] > 0.0)
-    damaged = (rsum <= 1e-12) & ((rsum_before > 1e-12) | src) \
-        & ~is_dest & alive
-    broken = jnp.any(damaged, axis=-1)                     # [S]
-    if rebuild_tasks is not None:
-        broken = broken | rebuild_tasks
     spt_sp = spt_result_slots(net, new_nbrs)
-    result = result / jnp.maximum(rsum[..., None], 1e-30)
-    result = jnp.where(rsum[..., None] > 1e-12, result, 0.0)
-    result = jnp.where(broken[:, None, None], spt_sp, result)
-    result = jnp.where(is_dest[..., None], 0.0, result)
-    return PhiSparse(data, local[..., None], result), new_nbrs
+    return _repair_slots(phi_sp, nbrs.out_mask, remap, valid,
+                         new_nbrs.out_mask, net.r, net.a, net.dest, spt_sp,
+                         rebuild_tasks), new_nbrs
 
 
 def refeasibilize_sparse_samegraph(net: CECNetwork, phi_sp: PhiSparse,
@@ -1254,50 +1278,26 @@ def refeasibilize_sparse_samegraph(net: CECNetwork, phi_sp: PhiSparse,
     repaired iterate, with the topology machinery peeled off.
 
     On the same graph `build_neighbors` memoizes to the identical
-    `Neighbors`, `_slot_remap` is the identity permutation and the
-    reslot gather is an exact copy, so the full repair reduces to the
-    masking/renormalization/damage arithmetic below — written in the
-    SAME operation order as `refeasibilize_sparse`, which is what makes
-    the reduction bitwise rather than merely close.  `spt_sp` lets the
+    `Neighbors` and `_slot_remap` is the identity permutation, so the
+    repair is the same compiled arithmetic (`_repair_slots`) on the
+    identity map, which is what makes it bitwise the full repair
+    rather than merely close.  `spt_sp` lets the
     caller supply `spt_result_slots(net, nbrs)` precomputed host-side
     (the per-unique-destination Dijkstra is the dominant per-event host
     cost at V > DENSE_V_LIMIT, and it depends only on the adjacency,
     the zero-flow link weights and `net.dest` — not on φ — so a churn
-    stream memoizes it per destination vector).  Every operation here
-    is an eager device op with NO host sync, which lets the fused churn
-    stream (sgp.FusedStream) fold the repair into its dispatch pipeline
-    without draining it.
+    stream memoizes it per destination vector).  The repair is one
+    device dispatch with NO host sync, which lets the fused churn
+    stream (sgp.FusedStream) fold it into its dispatch pipeline without
+    draining it.
     """
-    data = mask_slots(phi_sp.data, nbrs)
-    local = phi_sp.local[..., 0]
-    dsum = jnp.sum(data, axis=-1) + local
-    # missing mass goes to local offload
-    local = local + jnp.maximum(0.0, 1.0 - dsum)
-    tot = jnp.maximum(jnp.sum(data, axis=-1) + local, 1e-30)
-    data = data / tot[..., None]
-    local = local / tot
-
-    result = mask_slots(phi_sp.result, nbrs)
-    rsum = jnp.sum(result, axis=-1)                        # [S, V]
-    # on the same graph the reslot is an exact copy, so the pre-reslot
-    # sum the damage rule compares against IS rsum
-    rsum_before = rsum
-    S, V = net.S, net.V
-    is_dest = (jnp.arange(V)[None] == net.dest[:, None])   # [S, V]
-    alive = jnp.any(nbrs.out_mask, axis=-1)[None] | is_dest
-    src = (net.r * local > 1e-12) & (net.a[:, None] > 0.0)
-    damaged = (rsum <= 1e-12) & ((rsum_before > 1e-12) | src) \
-        & ~is_dest & alive
-    broken = jnp.any(damaged, axis=-1)                     # [S]
-    if rebuild_tasks is not None:
-        broken = broken | rebuild_tasks
     if spt_sp is None:
         spt_sp = spt_result_slots(net, nbrs)
-    result = result / jnp.maximum(rsum[..., None], 1e-30)
-    result = jnp.where(rsum[..., None] > 1e-12, result, 0.0)
-    result = jnp.where(broken[:, None, None], spt_sp, result)
-    result = jnp.where(is_dest[..., None], 0.0, result)
-    return PhiSparse(data, local[..., None], result)
+    V, D = nbrs.V, nbrs.Dmax
+    identity = np.broadcast_to(np.arange(D, dtype=np.int32), (V, D))
+    return _repair_slots(phi_sp, nbrs.out_mask, identity, nbrs.out_mask,
+                         nbrs.out_mask, net.r, net.a, net.dest, spt_sp,
+                         rebuild_tasks)
 
 # ----------------------------------------------------- dynamic task pool
 def next_pow2(n: int) -> int:

@@ -55,8 +55,9 @@ from .network import (CECNetwork, Neighbors, PhiSparse, build_buckets,
                       refeasibilize_sparse, refeasibilize_sparse_samegraph,
                       seed_task_slot, sparse_to_phi, spt_phi_sparse,
                       spt_result_slots)
-from .sgp import FusedStream, init_run_state, run_chunk
+from .sgp import FusedStream, init_run_state, run_chunk, warm_sigma
 from . import distributed as dist
+from .. import obs
 
 
 # ------------------------------------------------------------ invariants
@@ -65,16 +66,16 @@ def check_feasible(phi_sp: PhiSparse, nbrs: Neighbors,
     """Assert the edge-slot iterate is feasible.
 
     Data rows (slots + local column) lie on the simplex at every node;
-    result rows are simplex rows or exactly-empty rows (a node a churn
-    event just disconnected/reconnected carries no routing until the
-    next SGP step grows it one); destination rows carry no result mass;
-    padding slots hold EXACTLY zero.  The last check is deliberately
-    STRICTER than the `PhiSparse` layout contract (which lets padding
-    hold garbage because every consumer masks it): the SGP step and
-    `refeasibilize_sparse` both PRODUCE exactly-zero padding, and the
-    replay engine pins that so any new producer that starts leaving
-    scratch values in dead slots is flagged here instead of surfacing
-    as a confusing downstream diff.
+    result rows are simplex rows or exactly-empty rows (a failed node,
+    with no exits, carries no routing; `refeasibilize_sparse` gives a
+    recovered one its shortest-path row); destination rows carry no
+    result mass; padding slots hold EXACTLY zero.  The last check is
+    deliberately STRICTER than the `PhiSparse` layout contract (which
+    lets padding hold garbage because every consumer masks it): the SGP
+    step and `refeasibilize_sparse` both PRODUCE exactly-zero padding,
+    and the replay engine pins that so any new producer that starts
+    leaving scratch values in dead slots is flagged here instead of
+    surfacing as a confusing downstream diff.
 
     `active` ([S] bool, dynamic task-slot pools) splits the check:
     INACTIVE task rows are pinned to the inert-slot convention EXACTLY
@@ -441,10 +442,22 @@ class ReplayEngine:
         it through `refeasibilize_sparse` (re-slotting onto the new
         graph's index tiles, destination re-draws force-rebuilding the
         moved task).  Either way the driver state is re-initialized
-        from the WARM iterate — never from the SPT.
+        from the WARM iterate — never from the SPT — and keeps the
+        previous segment's sigma (`sgp.warm_sigma`).
+
+        Traced (`repro.obs`) as span `churn.apply`, with the repair in
+        `churn.repair` and the re-baseline in `churn.rebaseline`, and
+        counted in `churn.events` and `churn.topology_events`.
         """
+        obs.count("churn.events")
+        with obs.span("churn.apply"):
+            return self._apply_event(event)
+
+    def _apply_event(self, event) -> EventRecord:
         cost_before = float(self.state.costs[-1])
         kind = self.churn.apply(event)
+        if kind == "topology":
+            obs.count("churn.topology_events")
         net_new = self.churn.network()
         phi = self.phi
         if kind in ("task", "grow"):
@@ -458,43 +471,32 @@ class ReplayEngine:
                 phi = pad_phi_sparse(phi, int(net_new.S))
             phi = self._apply_task_repairs(net_new, phi)
         if kind in ("topology", "routing"):
-            rebuild = None
-            if isinstance(event, DestRedraw):
-                rebuild = np.zeros(net_new.S, bool)
-                rebuild[event.task] = True
-                rebuild = jnp.asarray(rebuild)
-            phi, self.nbrs = refeasibilize_sparse(net_new, phi, self.nbrs,
-                                                  rebuild_tasks=rebuild)
-            if self._active_dev is not None:
-                # a whole-iterate repair may write SPT rows into a slot
-                # the pool considers empty (e.g. routing churn aimed at
-                # a departed task) — pin the convention back
-                phi = mask_inactive_slots(phi, self._active_dev)
-            if self.bucketed:
-                self.buckets = build_buckets(net_new.adj)
-        if kind == "topology":
-            # the memoized SPT rows are adjacency-derived (see _spt_rows)
-            self._spt_cache.clear()
+            with obs.span("churn.repair"):
+                phi = self._repair(event, net_new, phi, kind)
         self.net = net_new
         self.cost_log.extend(self.state.costs)
         self._guard_log.extend(
             getattr(self.state, "guard_events", None) or [])
         if getattr(self.state, "guard_events", None):
             self.state.guard_events = []     # folded into _guard_log
-        if self.driver == "distributed" and kind != "topology":
-            # rate/routing events keep the graph (self.nbrs stays the
-            # memoized tiles the step was built from): swap the churned
-            # net into the compiled step instead of rebuilding it.  The
-            # fault rng takes the SAME per-segment engine split
-            # _init_state would — the rebaseline used to continue the
-            # previous segment's stream, silently breaking the
-            # independent-fault-streams contract on this path only
-            dist.rebaseline_distributed_state(
-                self.state, net_new, phi,
-                fault_rng=(self._segment_fault_rng()
-                           if self.fault_plan is not None else None))
-        else:
-            self._init_state(phi)             # warm re-baseline
+        sigma = warm_sigma(self.state.sigma)
+        with obs.span("churn.rebaseline"):
+            if self.driver == "distributed" and kind != "topology":
+                # rate/routing events keep the graph (self.nbrs stays the
+                # memoized tiles the step was built from): swap the
+                # churned net into the compiled step instead of
+                # rebuilding it.  The fault rng takes the SAME
+                # per-segment engine split _init_state would — the
+                # rebaseline used to continue the previous segment's
+                # stream, silently breaking the independent-fault-streams
+                # contract on this path only
+                dist.rebaseline_distributed_state(
+                    self.state, net_new, phi,
+                    fault_rng=(self._segment_fault_rng()
+                               if self.fault_plan is not None else None))
+            else:
+                self._init_state(phi)         # warm re-baseline
+            self.state.sigma = sigma
         self._drain_admissions()
         if self.invariant_checks:
             # post-event feasibility/loop-freedom spot check (see
@@ -509,6 +511,30 @@ class ReplayEngine:
         self.records.append(rec)
         self._segment_open = True
         return rec
+
+    def _repair(self, event, net_new: CECNetwork, phi: PhiSparse,
+                kind: str) -> PhiSparse:
+        """The iterate repaired onto `net_new` after a topology or
+        routing event (`self.nbrs` and the buckets follow the new
+        graph; a topology event drops the memoized SPT rows)."""
+        rebuild = None
+        if isinstance(event, DestRedraw):
+            rebuild = np.zeros(net_new.S, bool)
+            rebuild[event.task] = True
+            rebuild = jnp.asarray(rebuild)
+        phi, self.nbrs = refeasibilize_sparse(net_new, phi, self.nbrs,
+                                              rebuild_tasks=rebuild)
+        if self._active_dev is not None:
+            # a whole-iterate repair may write SPT rows into a slot
+            # the pool considers empty (e.g. routing churn aimed at
+            # a departed task) — pin the convention back
+            phi = mask_inactive_slots(phi, self._active_dev)
+        if self.bucketed:
+            self.buckets = build_buckets(net_new.adj)
+        if kind == "topology":
+            # the memoized SPT rows are adjacency-derived (see _spt_rows)
+            self._spt_cache.clear()
+        return phi
 
     def _apply_task_repairs(self, net_new: CECNetwork,
                             phi: PhiSparse) -> PhiSparse:
@@ -586,6 +612,7 @@ class ReplayEngine:
         pending = []
         for (t_ev, event) in window:
             stream.advance(t_ev - t_prev)
+            obs.count("churn.events")
             kind = self.churn.apply(event)
             assert kind != "grow", \
                 "_play_stream's pool probe must break the window " \

@@ -57,6 +57,19 @@ TRAFFIC_EPS = 1e-9   # rows with traffic below this take the one-hot jump
 # literal `sigma / 1.5` cannot be bitwise-mirrored on the host — an
 # explicit multiply compiles to the same op everywhere
 SIGMA_DECAY = np.float32(1.0 / 1.5)
+# past this the safeguard's sigma stops the driver (numerically stuck)
+SIGMA_STOP = np.float32(1e12)
+
+
+def warm_sigma(sigma: float) -> float:
+    """The safety factor a warm re-baseline starts its segment from:
+    the previous segment's, so an event does not send the driver back
+    to sigma = 1 (whose first steps overshoot and are rejected), unless
+    that segment stopped on a sigma blow-up.  Both warm re-baselines
+    (`replay.ReplayEngine.apply_event`, `FusedStream.rebaseline`) use
+    it; a cold start takes 1."""
+    sigma32 = np.float32(sigma)
+    return 1.0 if sigma32 > SIGMA_STOP else float(sigma32)
 
 
 @jax.tree_util.register_dataclass
@@ -759,7 +772,7 @@ def accept_step(new_cost: float, prev_cost: float, sigma: float,
     sigma32 = np.float32(sigma)
     if not accepted:
         sigma32 = sigma32 * np.float32(4.0)  # reject: step too aggressive
-        if sigma32 > np.float32(1e12):       # numerically stuck: stop
+        if sigma32 > SIGMA_STOP:             # numerically stuck: stop
             stopped = True
     else:
         sigma32 = max(sigma32 * SIGMA_DECAY, np.float32(1.0))
@@ -913,7 +926,7 @@ def _accept_update_impl(phi_new, fl_new, cost_new, phi, fl, sigma, prev,
     sigma_next = jnp.where(acc, jnp.maximum(sigma * SIGMA_DECAY, 1.0),
                            sigma * 4.0)
     sigma = jnp.where(live, sigma_next, sigma)
-    stop_sigma = live & ~acc & (sigma > 1e12)
+    stop_sigma = live & ~acc & (sigma > SIGMA_STOP)
     n_costs = n_costs + take.astype(jnp.int32)
     tol_hit = jnp.logical_and(
         tol > 0.0,
@@ -1242,7 +1255,8 @@ class FusedStream:
     exactly as `replay.ReplayEngine.apply_event` + `_init_state` would
     build a fresh `RunState` (the SAME eager `make_consts`, the same
     `flows_carry_and_cost_jit`, the same fault/guard re-inits on the
-    same values, sigma/n_costs/n_rej/stopped reset), but WITHOUT the
+    same values, n_costs/n_rej/stopped reset, sigma carried by
+    `warm_sigma`), but WITHOUT the
     per-event host syncs the event loop pays (`float(T0)` drains the
     pipeline; invariant checks drain it AND run an O(S·V²) closure).
     Identical eager ops on identical device values produce identical
@@ -1414,8 +1428,8 @@ class FusedStream:
         re-baseline the replay event loop performs.
 
         `repair`, if given, maps the current device φ to the repaired
-        one (routing events: `refeasibilize_sparse_samegraph`, all
-        eager device ops); rate events pass None — the iterate stays
+        one (routing events: `refeasibilize_sparse_samegraph`, device
+        ops only); rate events pass None — the iterate stays
         feasible as-is.  `net_new.adj` must equal the adjacency the
         state's `Neighbors` were built from; topology events must break
         the stream instead.  `fault_rng`/`rng` re-key the per-segment
@@ -1443,7 +1457,10 @@ class FusedStream:
         # fresh segment's Eq. 16 constants (the jitted compilation need
         # not round the d2_sup chains identically — see _make_consts_jit)
         self._consts = make_consts(net_new, T0, state.min_scale)
-        self._sigma = jnp.float32(1.0)
+        # `warm_sigma` on the device value: the segment's sigma carries
+        # over unless it blew up
+        self._sigma = jnp.where(self._sigma > SIGMA_STOP, jnp.float32(1.0),
+                                self._sigma)
         # bitwise jnp.float32(float(T0)), the fresh chunk's prologue
         self._prev = T0.astype(jnp.float32)
         self._n_costs = jnp.asarray(1, jnp.int32)

@@ -172,3 +172,46 @@ def test_host_driver_counts_each_cost_fetch():
     assert snap["counters"] == {"sgp.iterations": 4, "sgp.blocks": 4,
                                 "host_syncs": 6}
     assert "sgp.advance" not in snap["spans"]
+
+
+def test_churn_spans_and_counters():
+    """`ReplayEngine.apply_event` is one `churn.apply` span holding the
+    repair (`churn.repair`, topology and routing events only) and the
+    warm re-baseline (`churn.rebaseline`, every event), counted in
+    `churn.events` and `churn.topology_events`; tracing changes no
+    value the engine computes."""
+    net, phi0 = _geant()
+    hub = core.hub_node(net)
+    u, v = (int(x) for x in np.argwhere(np.asarray(net.adj))[0])
+    events = [core.RateScale(1.2), core.NodeFail(hub), core.LinkCut(u, v),
+              core.NodeRecover(hub), core.SourceRedraw(3, seed=5),
+              core.LinkRestore(u, v)]
+    engines = []
+    for on in (False, True):
+        eng = core.ReplayEngine(net, phi0=phi0, invariant_checks=False)
+        eng.iterate(2)
+        if on:
+            obs.enable()
+        for ev in events:
+            eng.apply_event(ev)
+            eng.iterate(2)
+        obs.disable()
+        engines.append(eng)
+    snap = obs.snapshot()
+    spans = snap["spans"]
+    assert spans["churn.apply"]["calls"] == 6
+    assert spans["churn.repair"]["calls"] == 5
+    assert spans["churn.rebaseline"]["calls"] == 6
+    assert spans["sgp.init"]["calls"] == 6
+    assert snap["counters"]["churn.events"] == 6
+    assert snap["counters"]["churn.topology_events"] == 4
+    inside = spans["churn.repair"]["total_s"] + \
+        spans["churn.rebaseline"]["total_s"]
+    assert spans["churn.apply"]["total_s"] >= inside
+    assert spans["churn.rebaseline"]["self_s"] == pytest.approx(
+        spans["churn.rebaseline"]["total_s"] - spans["sgp.init"]["total_s"],
+        abs=1e-12)
+    off, on = engines
+    assert off.costs == on.costs
+    for a, b in zip(jax.tree.leaves(off.phi), jax.tree.leaves(on.phi)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))

@@ -17,8 +17,11 @@ The replay engine's contract (core.replay) is locked four ways:
 
 * **Recovery regression** — a node that fails and then RECOVERS must
   keep the warm iterate: only rows that lost mass are rebuilt from the
-  SPT (dense `refeasibilize` as the bitwise oracle).  Before the
-  damaged-row fix, a recovery reset every task to the SPT tree.
+  SPT, and the recovered node's empty rows take their SPT rows (dense
+  `refeasibilize` as the bitwise oracle).  Before the damaged-row fix,
+  a recovery reset every task to the SPT tree; before the empty-row
+  fill, the recovered node's results were dropped
+  (`test_geant_churn_delivers_every_result`).
 
 * **Scale** (slow) — the canned `sw_1000_churn` schedule end-to-end,
   with per-event invariants and warm-start beating the cold restart.
@@ -27,8 +30,8 @@ The replay engine's contract (core.replay) is locked four ways:
   with ZERO intervening iterations on a crafted 6-node instance where
   the repaired iterate is predictable in closed form: the cut of a
   mass-free edge round-trips `refeasibilize_sparse` bitwise to φ⁰, and
-  a leaf node's fail/recover round-trips to φ⁰ with exactly that
-  node's result row zeroed.
+  so does a leaf node's fail/recover (its result row comes back as
+  its SPT row, which is φ⁰'s).
 """
 import numpy as np
 import pytest
@@ -245,6 +248,23 @@ def test_distributed_rate_event_reuses_compiled_step():
     check_invariants(eng.net, eng.phi, eng.nbrs)
 
 
+@pytest.mark.parametrize("driver", ["run", "distributed"])
+def test_event_keeps_warm_sigma(driver):
+    """An event's warm re-baseline starts the next segment from the
+    previous segment's sigma (back at 1, the first steps after every
+    event overshot and were rejected), unless that segment stopped on
+    a sigma blow-up."""
+    net, nbrs = _setup("abilene")
+    eng = core.ReplayEngine(net, phi0=core.spt_phi_sparse(net, nbrs),
+                            driver=driver)
+    eng.state.sigma = 16.0
+    eng.apply_event(core.RateScale(1.1))
+    assert eng.state.sigma == 16.0
+    eng.state.sigma = 4.0 ** 21                     # past the blow-up stop
+    eng.apply_event(core.RateScale(0.9))
+    assert eng.state.sigma == 1.0
+
+
 # -------------------------------------------------------- property loop
 @pytest.mark.parametrize("seed", [1, 7])
 def test_randomized_schedule_invariants(seed):
@@ -365,7 +385,8 @@ def test_dest_redraw_rebuilds_moved_task():
 def test_recovery_keeps_warm_iterate():
     """THE refeasibilize recovery-gap regression: fail a node, adapt,
     recover it — the repaired iterate must keep the adapted routing
-    (only rows that LOST mass rebuild from the SPT), match the dense
+    (only rows that LOST mass rebuild from the SPT, the recovered
+    node's empty rows take their SPT rows), match the dense
     `refeasibilize` oracle bitwise, and stay loop-free.  Under the old
     any-empty-row-is-broken policy every task snapped back to the SPT
     tree, silently discarding the warm start."""
@@ -397,22 +418,28 @@ def test_recovery_keeps_warm_iterate():
     # rebuild are those the recovered node immediately computes direct
     # input for (its result row must carry that flow; leaving it empty
     # would drop it from the objective); every other task keeps its
-    # adapted routing, merely renormalized.  Under the old policy every
+    # adapted routing, merely renormalized, with the recovered node's
+    # empty row filled from the SPT (left empty, it would drop the
+    # results later steps route into it).  Under the old policy every
     # task snapped to the SPT here.
     res_f = np.asarray(core.sparse_to_phi(sp_f, nbrs_f, net.V).result,
                        dtype=np.float64)
     rs = res_f.sum(-1, keepdims=True)
-    renorm = np.where(rs > 1e-12, res_f / np.maximum(rs, 1e-30), 0.0)
+    spt_dense = np.asarray(core.spt_phi(net).result)
+    renorm = np.where(rs > 1e-12, res_f / np.maximum(rs, 1e-30), spt_dense)
     local = np.asarray(sp_r.local[..., 0])
     is_dest = (np.arange(net.V)[None] == np.asarray(net.dest)[:, None])
     sourced = ((np.asarray(net.r) * local > 1e-12)
                & (rs[..., 0] <= 1e-12) & ~is_dest)
     must_rebuild = sourced.any(-1)                      # [S]
     assert not must_rebuild.all(), "no task should keep warm state?!"
-    spt_dense = np.asarray(core.spt_phi(net).result)
     expected = np.where(must_rebuild[:, None, None], spt_dense, renorm)
     np.testing.assert_allclose(np.asarray(got.result), expected, atol=1e-6,
                                err_msg="recovery rebuilt undamaged rows")
+    # the recovered hub routes the results of every task not destined
+    # to it, where the old policy left its rows empty
+    hub_rows = np.asarray(got.result)[:, hub].sum(-1)
+    assert (hub_rows[~is_dest[:, hub]] > 0.5).all()
     spt_sp = np.asarray(core.gather_edges(core.spt_phi(net).result, nbrs_r))
     res_r = np.asarray(sp_r.result)
     reset = [np.array_equal(res_r[s], spt_sp[s]) for s in range(net.S)]
@@ -531,9 +558,10 @@ def test_link_cut_restore_roundtrip_is_bitwise_identity():
 def test_node_fail_recover_roundtrip_zeroes_only_failed_row():
     """Fail node 5 (it loses every exit), recover it immediately: every
     OTHER row round-trips bitwise to φ⁰, and node 5's result row comes
-    back exactly zero — it is flow-free (r[0,5]=0) so the recovery
-    repair must leave it empty rather than SPT-rebuild it (empty rows
-    of non-source nodes are feasible and cost nothing)."""
+    back as its SPT row — it is flow-free (r[0,5]=0), so the task is
+    not rebuilt, but an empty row would drop the results later steps
+    route into node 5.  φ⁰ is the SPT, so the whole round trip is a
+    bitwise identity."""
     net = _line_net()
     nbrs = core.build_neighbors(net.adj)
     phi0 = core.spt_phi_sparse(net, nbrs)
@@ -548,8 +576,9 @@ def test_node_fail_recover_roundtrip_zeroes_only_failed_row():
 
     np.testing.assert_array_equal(np.asarray(nbrs_r.out_nbr),
                                   np.asarray(nbrs.out_nbr))
-    want_result = np.asarray(phi0.result).copy()
-    want_result[0, 5] = 0.0                        # the one allowed change
+    # node 5's row was emptied by the failure and refilled from the SPT
+    assert np.asarray(phi_f.result)[0, 5].sum() == 0.0
+    want_result = np.asarray(phi0.result)
     np.testing.assert_array_equal(np.asarray(phi_r.data),
                                   np.asarray(phi0.data))
     np.testing.assert_array_equal(np.asarray(phi_r.local),
@@ -559,6 +588,89 @@ def test_node_fail_recover_roundtrip_zeroes_only_failed_row():
     # and the round-tripped iterate still descends
     _, h = core.run(net, phi_r, n_iters=4, method="sparse")
     assert h["final_cost"] <= h["costs"][0] * (1.0 + 1e-12)
+
+
+# ------------------------------------------------------- GEANT delivery
+def _queue_cost(F, cap):
+    """M/M/1 delay F/(cap - F), continued above 0.95 cap as its
+    second-order expansion (the queue family the scenarios use)."""
+    Fs = 0.95 * cap
+    inner = np.minimum(F, Fs)
+    dF = F - Fs
+    ext = (Fs / (cap - Fs) + cap / (cap - Fs) ** 2 * dF
+           + cap / (cap - Fs) ** 3 * dF ** 2)
+    return np.where(F <= Fs, inner / (cap - inner), ext)
+
+
+def _numpy_flows(net, phi, nbrs):
+    """(largest undelivered share of a task's results, cost, loop-free)
+    of the edge-slot φ on `net`, by plain numpy: the data and result
+    flow equations t = inject + Φᵀ t solved with `numpy.linalg.solve`,
+    one task at a time, on dense [V, V] routing matrices.  A support
+    with a loop reads (inf, nan, False): its equations have no solution."""
+    adj = np.asarray(net.adj)
+    V = adj.shape[0]
+    nbr = np.asarray(nbrs.out_nbr)
+    mask = np.asarray(nbrs.out_mask)
+    data = np.asarray(phi.data, np.float64) * mask
+    res = np.asarray(phi.result, np.float64) * mask
+    local = np.asarray(phi.local, np.float64)[..., 0]
+    r = np.asarray(net.r, np.float64)
+    a = np.asarray(net.a, np.float64)
+    w = np.asarray(net.w, np.float64)
+    dest = np.asarray(net.dest)
+    rows = np.repeat(np.arange(V)[:, None], nbr.shape[1], axis=1)
+    eye = np.eye(V)
+    loss = 0.0
+    F, G = np.zeros((V, V)), np.zeros(V)
+    for s in range(data.shape[0]):
+        Pd, Pr = np.zeros((V, V)), np.zeros((V, V))
+        np.add.at(Pd, (rows, nbr), data[s])
+        np.add.at(Pr, (rows, nbr), res[s])
+        for P in (Pd, Pr):        # a loop-free support is nilpotent
+            sup = (P > 0).astype(np.float64)
+            if np.linalg.matrix_power(sup, V).any():
+                return np.inf, np.nan, False
+        t = np.linalg.solve(eye - Pd.T, r[s])
+        g = t * local[s]
+        t_res = np.linalg.solve(eye - Pr.T, a[s] * g)
+        made = a[s] * g.sum()
+        if made > 0.0:
+            loss = max(loss, abs(made - t_res[dest[s]]) / made)
+        F += t[:, None] * Pd + t_res[:, None] * Pr
+        G += w[s] * g
+    cap = np.asarray(net.link_cost.params, np.float64)
+    comp = np.asarray(net.comp_cost.params, np.float64)
+    cost = (np.sum(np.where(adj, _queue_cost(F, cap), 0.0))
+            + np.sum(_queue_cost(G, comp)))
+    return loss, float(cost), True
+
+
+def test_geant_churn_delivers_every_result():
+    """The first 27 events of a seeded GEANT churn stream (three node
+    failures and recoveries, link cuts of the one degree-4 node, rate
+    and routing events), through `apply_event` + `iterate` as an
+    operator drives it: after EVERY event each task's results all reach
+    its destination, the reported cost is the plain numpy cost of the
+    live φ, and both supports are loop-free.  Before the empty-row fill
+    of `refeasibilize_sparse`, a recovered node's empty result row
+    dropped up to 87 % of a task's results on this stream, while the
+    reported cost, which leaves the dropped results out too, still
+    matched."""
+    net = core.make_scenario(core.TABLE_II["geant"])
+    events = core.random_schedule(net, 60, seed=1).events[:27]
+    kinds = [type(ev) for _, ev in events]
+    assert kinds.count(core.NodeFail) == kinds.count(core.NodeRecover) == 3
+    eng = core.ReplayEngine(net, invariant_checks=False)
+    eng.iterate(20)
+    for i, (_, ev) in enumerate(events):
+        eng.apply_event(ev)
+        eng.iterate(1 + i % 3)
+        loss, cost, loop_free = _numpy_flows(eng.net, eng.phi, eng.nbrs)
+        assert loop_free, (i, ev)
+        assert loss <= 1e-5, (i, ev, loss)
+        np.testing.assert_allclose(eng.cost, cost, rtol=1e-5,
+                                   err_msg=f"event {i}: {ev}")
 
 
 # ----------------------------------------------------------------- scale
