@@ -161,11 +161,10 @@ def project_rows(phi_row: jnp.ndarray, delta: jnp.ndarray, M: jnp.ndarray,
 
     All inputs are [..., K]; fully vectorized over leading dims.
     This is the pure-jnp oracle for kernels/simplex_project; the Pallas
-    kernel solves the same dual with the original division-form
-    fixed-`n_iter` bisection, so the two agree to the bisection's
-    resolution (locked at 1e-4 in the kernel tests), not bitwise —
-    mirroring the hoisted form + early exit there is a TPU-validation
-    task for an accelerator session.
+    kernel runs the same hoisted form for a fixed `n_iter` rounds, which
+    end on the bracket this early exit stops at.  The two differ only in
+    the order of the float32 sum over K (locked at 1e-4 in the kernel
+    tests), not bitwise.
     """
     Msafe = jnp.where(permitted, jnp.maximum(M, 1e-12), 1.0)
     phi0 = jnp.where(permitted, phi_row, 0.0)
@@ -257,8 +256,7 @@ def _project(phi_rows: jnp.ndarray, delta: jnp.ndarray, M: jnp.ndarray,
     impl="oracle" keeps the in-module pure-jnp `project_rows`; anything
     else flattens to [S·V, K] and goes through
     `repro.kernels.ops.simplex_project` (backend dispatch: Pallas kernel
-    on TPU, jnp reference on CPU, "pallas_interpret" for validation —
-    the wrapper pads K to the 128-lane boundary for the kernel paths).
+    on TPU, jnp reference on CPU, "pallas_interpret" for validation).
     """
     if impl == "oracle":
         return project_rows(phi_rows, delta, M, permitted)

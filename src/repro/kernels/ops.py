@@ -185,21 +185,12 @@ def simplex_project(phi, delta, M, permitted, impl: Optional[str] = None,
                     **kw):
     """Batched Eq. 15 QP rows [R, K].
 
-    For the kernel paths, K is padded up to the 128-lane boundary here
-    (padded coordinates are blocked, so the kernel returns 0 for them
-    and the pad is sliced off); the jnp reference takes K as-is.
+    The kernel paths take K as-is: the kernel lays the rows along the
+    lanes (kernels/simplex_project.py), so no slot is padded to the lane
+    width.
     """
     mode = _pick(impl, "simplex_project")
     if mode == "ref":
         return _ref.simplex_project_ref(phi, delta, M, permitted)
-    K = phi.shape[-1]
-    Kp = ((K + 127) // 128) * 128
-    if Kp != K:
-        pad = ((0, 0), (0, Kp - K))
-        phi = jnp.pad(phi, pad)
-        delta = jnp.pad(delta, pad)
-        M = jnp.pad(M, pad, constant_values=1.0)
-        permitted = jnp.pad(permitted, pad)
-    out = _proj_pallas(phi, delta, M, permitted,
-                       interpret=(mode == "pallas_interpret"), **kw)
-    return out[:, :K]
+    return _proj_pallas(phi, delta, M, permitted,
+                        interpret=(mode == "pallas_interpret"), **kw)

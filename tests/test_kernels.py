@@ -94,19 +94,38 @@ def test_moe_gmm(E, C, D, F, dtype):
         **_tol(dtype))
 
 
-@pytest.mark.parametrize("R,K", [(17, 8), (100, 24), (64, 112)])
-def test_simplex_project(R, K):
+def _rk(R, K, case=None):
+    return pytest.param(R, K, case,
+                        id="-".join(str(a) for a in (R, K, case) if a))
+
+
+@pytest.mark.parametrize("R,K,case", [
+    _rk(17, 8), _rk(100, 24), _rk(64, 112),
+    # the solve cells' data and result rows: geant (S·V = 880, Dmax 4)
+    # and sw_queue (12000, Dmax 9); neither R is a multiple of 128
+    _rk(880, 5), _rk(880, 4), _rk(12000, 10), _rk(12000, 9),
+    _rk(1000, 10, "blocked_rows"), _rk(300, 7, "M_floor"),
+])
+def test_simplex_project(R, K, case):
     ks = jax.random.split(KEY, 4)
     phi = jax.nn.softmax(jax.random.normal(ks[0], (R, K)), -1)
     delta = jax.nn.softplus(jax.random.normal(ks[1], (R, K)))
     M = jax.nn.softplus(jax.random.normal(ks[2], (R, K)))
     perm = jax.random.bernoulli(ks[3], 0.7, (R, K))
     perm = perm.at[:, 0].set(True)
+    live = np.ones(R, bool)
+    if case == "blocked_rows":          # every third row fully blocked
+        live[::3] = False
+        perm = perm & jnp.asarray(live)[:, None]
+    if case == "M_floor":               # scalings at and near the 1e-12 floor
+        M = M * 1e-12
     out = ops.simplex_project(phi, delta, M, perm, impl="pallas_interpret")
     want = ref.simplex_project_ref(phi, delta, M, perm)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out.sum(-1)), 1.0, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out.sum(-1))[live], 1.0,
+                               atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(out)[~live], 0.0)
 
 
 def test_kernel_sgp_step_equivalence():

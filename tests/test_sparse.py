@@ -241,7 +241,7 @@ def test_fully_blocked_rows_project_to_zero():
 
 def test_step_projection_impl_switch():
     """proj_impl routes both row projections through kernels.ops: the
-    interpreted Pallas kernel (K padded to 128 lanes) and the jnp
+    interpreted Pallas kernel (rows along the lanes) and the jnp
     oracle agree through one full SGP step."""
     net, phi, nbrs = _setup("abilene")
     consts = make_consts(net, core.total_cost(net, phi))

@@ -13,6 +13,7 @@ loads the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,9 +119,27 @@ def test_edge_rounds_bucketed_ba_10000(chip, ba_10000_buckets):
 def test_simplex_project_sw_1000(chip, sw_1000):
     net, nbrs, _ = sw_1000
     impl = ops.default_impl("simplex_project", "tpu")
-    rows = (net.S * net.V, nbrs.Dmax + 1)      # padded to 128 lanes in ops
+    rows = (net.S * net.V, nbrs.Dmax + 1)      # [R, K], K not padded
     f = jax.ShapeDtypeStruct(rows, jnp.float32, sharding=chip)
     b = jax.ShapeDtypeStruct(rows, jnp.bool_, sharding=chip)
+    compiled = _compile(
+        lambda p, d, m, k: ops.simplex_project(p, d, m, k, impl=impl),
+        f, f, f, b)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the trace finds the kernel by this instruction name (qp_roofline)
+    assert re.search(r"%simplex_project(\.\d+)? = \S+ custom-call\(", text)
+
+
+def test_simplex_project_ba_10000(chip, ba_10000_buckets):
+    """The widest rows the repo projects, K = Dmax + 1 = 278: one
+    [K, 8, 128] block must fit the kernel's VMEM."""
+    spec = core.TABLE_II["ba_10000"]
+    D = max(int(t.shape[1]) for t in ba_10000_buckets.out.nbr)
+    rows = (spec.S * ba_10000_buckets.V, D + 1)
+    f = jax.ShapeDtypeStruct(rows, jnp.float32, sharding=chip)
+    b = jax.ShapeDtypeStruct(rows, jnp.bool_, sharding=chip)
+    impl = ops.default_impl("simplex_project", "tpu")
     compiled = _compile(
         lambda p, d, m, k: ops.simplex_project(p, d, m, k, impl=impl),
         f, f, f, b)
